@@ -7,7 +7,7 @@ unified memory on 1-2 GPUs. Our strategies (DESIGN.md §2):
                   (interpret mode on CPU: validates, does not accelerate)
   async_batched — scan over particle batches (the async extension)
   fused         — single-pass push+deposit (kernels/fused_cycle.py on TPU,
-                  windowed-scatter jnp elsewhere)
+                  flattened-deposit jnp elsewhere)
 Also benchmarked: the full-cycle comparison the fused strategy exists for —
 the seed-style two-pass cycle (push, then re-read the particles to deposit)
 vs the fused single pass with donated buffers — plus the deposit scatter
@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from benchmarks.common import row, time_chained, time_fn
-from repro.core.grid import Grid1D, deposit, deposit_windowed
+from repro.core.grid import Grid1D, deposit
 from repro.core.mover import push, push_fused, push_unified
 from repro.core.particles import init_uniform
 from repro.kernels import ops
@@ -67,10 +67,6 @@ def bench(n: int = N, nc: int = NC, iters: int = 5,
     us = time_fn(dep_x, buf, iters=iters)
     rows.append(row("deposit/xla_scatter", us, ""))
     results["deposit_xla_scatter_us"] = us
-    dep_w = jax.jit(lambda b: deposit_windowed(grid, b.x, b.w * b.alive))
-    us = time_fn(dep_w, buf, iters=iters)
-    rows.append(row("deposit/windowed_scatter", us, ""))
-    results["deposit_windowed_scatter_us"] = us
     dep_k = jax.jit(lambda b: ops.deposit(b.x, b.w * b.alive, x0=0.0,
                                           dx=grid.dx, nc=grid.nc,
                                           ng=grid.ng))

@@ -180,7 +180,10 @@ print("RESULTJSON " + json.dumps({
 
 def _measure(d: int, *, nc: int, n: int, async_n: int, iters: int,
              max_migration: int, rebalance_every: int, scenario: str,
-             max_births: int, ckpt_every: int = 2) -> dict | None:
+             max_births: int, ckpt_every: int = 2) -> dict:
+    """Run one measurement in a child process (the only process of the
+    sweep that touches a device); a child that prints no result fails the
+    sweep."""
     params = json.dumps(dict(d=d, nc=nc, n=n, async_n=async_n, iters=iters,
                              m=max_migration, rebalance_every=rebalance_every,
                              scenario=scenario, max_births=max_births,
@@ -197,26 +200,21 @@ def _measure(d: int, *, nc: int, n: int, async_n: int, iters: int,
     for line in out.stdout.splitlines():
         if line.startswith("RESULTJSON "):
             return json.loads(line[len("RESULTJSON "):])
-    print(f"# domains={d} FAILED:\n{out.stderr[-2000:]}", file=sys.stderr)
-    return None
+    raise RuntimeError(
+        f"scenario={scenario} domains={d}: the child exited "
+        f"{out.returncode} with no result:\n{out.stderr[-2000:]}")
 
 
 def _sweep_checkpoint(domains, *, nc: int, n: int, async_n: int, iters: int,
                       max_migration: int, max_births: int,
                       ckpt_every: int = 2) -> tuple[list[str], dict]:
     """The checkpoint-overhead sweep (its own record shape — no phases)."""
-    per_domain = {}
-    for d in domains:
-        res = _measure(d, nc=nc, n=n, async_n=async_n, iters=iters,
-                       max_migration=max_migration, rebalance_every=0,
-                       scenario="checkpoint", max_births=max_births,
-                       ckpt_every=ckpt_every)
-        if res is not None:
-            per_domain[d] = res
-    if not per_domain:
-        raise RuntimeError(
-            f"checkpoint bench produced no results for domains={domains} "
-            f"(see stderr above for failures)")
+    per_domain = {
+        d: _measure(d, nc=nc, n=n, async_n=async_n, iters=iters,
+                    max_migration=max_migration, rebalance_every=0,
+                    scenario="checkpoint", max_births=max_births,
+                    ckpt_every=ckpt_every)
+        for d in domains}
     payload = {
         "async_n": async_n, "ckpt_every": ckpt_every,
         "config": {"nc": nc, "n_per_species": n, "iters": iters,
@@ -236,17 +234,10 @@ def _sweep_ensemble(widths, *, nc: int, n: int,
     """The ensemble-width sweep (single device; ``domains`` keys are member
     widths). Each width measures W heterogeneous parameter points through
     ONE compiled vmapped step on the full-churn resilience workload."""
-    per_width = {}
-    for w in widths:
-        res = _measure(w, nc=nc, n=n, async_n=1, iters=iters,
-                       max_migration=0, rebalance_every=0,
-                       scenario="ensemble", max_births=0)
-        if res is not None:
-            per_width[w] = res
-    if not per_width:
-        raise RuntimeError(
-            f"ensemble bench produced no results for widths={widths} "
-            f"(see stderr above for failures)")
+    per_width = {
+        w: _measure(w, nc=nc, n=n, async_n=1, iters=iters, max_migration=0,
+                    rebalance_every=0, scenario="ensemble", max_births=0)
+        for w in widths}
     payload = {
         "config": {"nc": nc, "n_per_species": n, "iters": iters},
         "domains": {str(w): per_width[w] for w in per_width},
@@ -278,21 +269,14 @@ def sweep(domains=(1, 2, 4, 8), *, nc: int = 4096, n: int = 131_072,
         return _sweep_ensemble(domains, nc=nc, n=min(n, 16_384),
                                iters=iters)
     per_domain, per_domain_queues = {}, {}
-    engine_knobs = None
     for d in domains:
         res = _measure(d, nc=nc, n=n, async_n=async_n, iters=iters,
                        max_migration=max_migration,
                        rebalance_every=rebalance_every, scenario=scenario,
                        max_births=max_births)
-        if res is not None:
-            per_domain[d] = res["probe"]
-            per_domain_queues[d] = res["queues"]
-            engine_knobs = res["engine"]
-    if not per_domain:
-        # every subprocess died: surface it instead of exiting 0 with no JSON
-        raise RuntimeError(
-            f"engine scaling bench produced no results for domains={domains}"
-            f" scenario={scenario} (see stderr above for failures)")
+        per_domain[d] = res["probe"]
+        per_domain_queues[d] = res["queues"]
+        engine_knobs = res["engine"]
     metrics = perf.scaling_metrics(per_domain)
     payload = {
         "async_n": async_n,

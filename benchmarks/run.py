@@ -18,6 +18,10 @@ size plus a small scaling sweep (the CI configuration, see
 ``scripts/ci.sh``); ``--profile-dir DIR`` captures a jax profiler trace
 of the in-process benchmark work (the engine's named phase scopes appear
 as Perfetto/TensorBoard ranges).
+
+One process per device: the scaling sweep measures in child processes, so
+it runs first, while this process has not touched a device yet; the
+in-process benchmarks run after it. A failed child fails the run.
 """
 
 from __future__ import annotations
@@ -47,12 +51,16 @@ def main() -> None:
                          "benchmark work into this directory")
     args = ap.parse_args()
 
+    from repro.launch.cache import enable_compilation_cache
     from repro.obs import tracing
 
-    from benchmarks import bench_mover_strategies
+    from benchmarks import bench_mover_strategies, bench_scaling
 
+    enable_compilation_cache()           # exported to the sweep's children
     print("name,us_per_call,derived")
     if args.smoke:
+        for r in bench_scaling.smoke(args.scaling_json):
+            print(f"smoke_scaling/{r}", flush=True)
         with tracing.trace_session(args.profile_dir or None):
             rows, results = bench_mover_strategies.bench(n=65_536, nc=1_024,
                                                          iters=3)
@@ -60,16 +68,12 @@ def main() -> None:
             print(f"smoke_strategies/{r}", flush=True)
         results["mode"] = "smoke"
         _write_json(args.json, results)
-        from benchmarks import bench_scaling
-        for r in bench_scaling.smoke(args.scaling_json):
-            print(f"smoke_scaling/{r}", flush=True)
         return
 
-    from benchmarks import (bench_hybrid_total, bench_ionization, bench_lm,
-                            bench_scaling)
+    from benchmarks import bench_hybrid_total, bench_ionization, bench_lm
     modules = [
-        ("fig3_hybrid_total", bench_hybrid_total),
         ("fig4_scaling", bench_scaling),
+        ("fig3_hybrid_total", bench_hybrid_total),
         ("fig7_8_strategies", bench_mover_strategies),
         ("sec3_ionization", bench_ionization),
         ("lm_substrate", bench_lm),

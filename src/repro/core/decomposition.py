@@ -30,11 +30,6 @@ from jax.sharding import Mesh
 
 from repro.core.pic import PICConfig, PICState
 from repro.distributed import engine as _engine
-# re-exported for back-compat: the version-agnostic shard_map wrapper and
-# ring helpers now live with the communication layer
-from repro.distributed.halo import (ppermute_tree as _ppermute_tree,  # noqa: F401
-                                    rank as _rank, ring_perm as _nperm,
-                                    shard_map)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -64,30 +64,23 @@ def deposit(grid: Grid1D, buf: SpeciesBuffer, charge: float) -> Array:
     return rho / grid.dx
 
 
-def deposit_windowed(grid: Grid1D, x: Array, q: Array) -> Array:
-    """CIC deposition as ONE windowed scatter-add (the fused-cycle fast path).
+def deposit_flat(grid: Grid1D, x: Array, q: Array) -> Array:
+    """CIC deposition of charges ``q`` at positions ``x``, any shape.
 
-    CIC writes every particle's charge to the two CONTIGUOUS nodes (i, i+1),
-    so instead of two scalar scatters of N updates each we issue a single
-    ``lax.scatter_add`` whose update window is the length-2 node slice — half
-    the scatter rows, one traversal. ``_cic_weights`` clips i to
+    x/q are flattened, which is how the stacked multi-species deposit
+    collapses S sequential scatters into one pass. The two CIC nodes are
+    two scalar scatter-adds. (A single scatter with a length-2 update window
+    is faster on CPU, but its (N, 2) updates put 2 in the TPU's 128-lane
+    minor tile: 25 GB at 3 x 16 Mi particles.) ``_cic_weights`` clips i to
     [0, nc-1], so i+1 <= ng-1 and PROMISE_IN_BOUNDS is safe (it removes
-    XLA's per-update clamping, the other half of the win on CPU).
-
-    x/q may be any shape; they are flattened, which is how the stacked
-    multi-species deposit collapses S sequential scatters into one.
+    XLA's per-update clamping).
     """
     xf = x.reshape(-1)
     qf = q.reshape(-1).astype(xf.dtype)
     i, f = _cic_weights(grid, xf)
-    upd = jnp.stack([qf * (1.0 - f), qf * f], axis=-1)       # (N, 2)
-    dnums = jax.lax.ScatterDimensionNumbers(
-        update_window_dims=(1,), inserted_window_dims=(),
-        scatter_dims_to_operand_dims=(0,))
-    rho = jax.lax.scatter_add(
-        jnp.zeros((grid.ng,), xf.dtype), i[:, None], upd, dnums,
-        indices_are_sorted=False, unique_indices=False,
-        mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS)
+    rho = jnp.zeros((grid.ng,), xf.dtype)
+    rho = rho.at[i].add(qf * (1.0 - f), mode="promise_in_bounds")
+    rho = rho.at[i + 1].add(qf * f, mode="promise_in_bounds")
     return rho / grid.dx
 
 
@@ -99,7 +92,7 @@ def deposit_stacked(grid: Grid1D, x: Array, w: Array, alive: Array,
     ride along (cheaper than branching per species under jit).
     """
     q = charges[:, None] * w * alive
-    return deposit_windowed(grid, x, q)
+    return deposit_flat(grid, x, q)
 
 
 def deposit_density(grid: Grid1D, buf: SpeciesBuffer) -> Array:

@@ -20,8 +20,8 @@ data movement. The TPU/JAX mapping (DESIGN.md §2):
   particles, so the cycle reads the particle arrays from HBM once instead of
   twice. On TPU this is the ``kernels/fused_cycle.py`` Pallas kernel (the
   deposit accumulates in VMEM while the tile is resident); on other backends
-  a pure-jnp equivalent whose deposition is ONE windowed scatter-add
-  (``grid.deposit_windowed``) instead of two scalar scatters.
+  a pure-jnp equivalent whose deposition is one flattened pass over all
+  species (``grid.deposit_flat``).
 
 Every strategy returns a ``PushResult`` carrying the wall-hit masks of this
 push. The masks are what the plasma-wall sources (SEE / sputtering,
@@ -43,7 +43,7 @@ from typing import Literal, NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.grid import (Grid1D, deposit_stacked, deposit_windowed,
+from repro.core.grid import (Grid1D, deposit_stacked, deposit_flat,
                              gather, gather_onehot)
 from repro.core.particles import SpeciesBuffer, StackedSpecies
 
@@ -183,7 +183,7 @@ def push_fused(buf: SpeciesBuffer, e: Array, grid: Grid1D, qm: float,
     ``deposit_charge * w * alive`` lands in ``PushResult.rho`` — computed in
     the same pass over the particle arrays as the push itself, so HBM sees
     them once. On TPU this runs as the ``kernels/fused_cycle.py`` Pallas
-    kernel; elsewhere as pure jnp with the windowed one-scatter deposit.
+    kernel; elsewhere as pure jnp with the flattened deposit.
     ``rho_carry`` seeds the deposit accumulator (the Pallas kernel's VMEM
     accumulator starts from it instead of zeros): callers accumulating a
     multi-call rho — per-queue engine loops, pre-deposited birth charge —
@@ -212,7 +212,7 @@ def push_fused(buf: SpeciesBuffer, e: Array, grid: Grid1D, qm: float,
     w = buf.w * alive
     rho = None
     if deposit_charge is not None:
-        rho = deposit_windowed(grid, x, deposit_charge * w)
+        rho = deposit_flat(grid, x, deposit_charge * w)
         if rho_carry is not None:
             rho = rho_carry + rho
     out = dataclasses.replace(buf, x=x, v=v, alive=alive, w=w)
@@ -280,7 +280,7 @@ def push_stacked(st: StackedSpecies, e: Array, grid: Grid1D, qm: Array,
 
     ``qm`` and ``dt`` are (S,) per-species arrays (q/m and dt*stride). When
     ``charges`` (S,) is given the post-push TOTAL charge density of all
-    species is deposited in the same pass (one flattened windowed scatter)
+    species is deposited in the same pass (one flattened deposit)
     and returned as ``rho``; pass None to skip deposition. ``rho_carry``
     seeds the deposit accumulator — the distributed engine threads its
     per-queue rho through here so the accumulation is part of the fused

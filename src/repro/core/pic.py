@@ -11,7 +11,7 @@ Hot-loop structure (this file is the perf-critical assembly):
 * same-capacity species are stacked into ONE ``StackedSpecies`` (S, cap)
   pytree and pushed with a single ``vmap``'d Boris kernel over the species
   axis — no per-species Python loop, and the field deposit collapses S
-  sequential scatters into one flattened windowed scatter;
+  sequential scatters into one flattened deposit;
 * every mover strategy reports its wall-hit masks directly
   (``mover.PushResult``), so the plasma-wall emission source consumes the
   masks of THE push — each species is pushed exactly once per step;
@@ -35,7 +35,7 @@ import jax.numpy as jnp
 from repro.core import collisions, diagnostics, fields, mover
 from repro.core.params import RuntimeParams, b_active
 from repro.core.grid import Grid1D, deposit, deposit_stacked
-from repro.core.grid import deposit_windowed
+from repro.core.grid import deposit_flat
 from repro.core.particles import (SpeciesBuffer, init_uniform, stack_species,
                                   unstack_species)
 
@@ -181,7 +181,7 @@ def init_state(cfg: PICConfig, seed: int = 0) -> PICState:
 
 
 def compute_rho(cfg: PICConfig, species: tuple[SpeciesBuffer, ...]) -> Array:
-    """Total charge density: one flattened (S*cap,) windowed scatter when the
+    """Total charge density: one flattened (S*cap,) deposit when the
     species stack, the per-species scatter loop otherwise."""
     grid = cfg.grid
     if _stackable(cfg):
@@ -364,7 +364,7 @@ def step_fn(state: PICState, cfg: PICConfig,
             q_t = cfg.species[target].charge
             if carried and new_rho is not None and q_t != 0.0:
                 # birth charge folds into the carried in-pass deposit
-                new_rho = new_rho + deposit_windowed(
+                new_rho = new_rho + deposit_flat(
                     grid, erows.x, q_t * erows.w * erows.ok)
             diag.update({f"{cfg.species[target].name}/{k}": v
                          for k, v in d.items()})
@@ -380,12 +380,12 @@ def step_fn(state: PICState, cfg: PICConfig,
             cfg.dt if rp is None else rp.dt)
         species[ni], species[ei], species[ii] = neu, ele, ion
         if carried and new_rho is not None:
-            # one windowed scatter for both halves of every born pair; the
+            # one flattened deposit for both halves of every born pair; the
             # killed neutral carries no charge (see _carries_rho)
             q_e = cfg.species[ei].charge
             q_i = cfg.species[ii].charge
             bw = births.w * births.ok
-            new_rho = new_rho + deposit_windowed(
+            new_rho = new_rho + deposit_flat(
                 grid, jnp.stack([births.x, births.x]),
                 jnp.stack([q_e * bw, q_i * bw]))
         diag.update(d)

@@ -112,7 +112,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import boundaries, collisions, diagnostics, mover
 from repro.core.grid import (Grid1D, deposit_density, deposit_stacked,
-                             deposit_windowed)
+                             deposit_flat)
 from repro.core.particles import (FreeSlotRing, SpeciesBuffer, StackedSpecies,
                                   init_uniform, inject_at, inject_masked,
                                   kill, kill_packed, ring_claim,
@@ -353,36 +353,38 @@ def _group_pending_rows(ecfg: EngineConfig, groups) -> list[int]:
     return rows
 
 
+# The queue views below never form a (cap / n, n) array: on TPU the minor
+# dimension of an array is tiled to 128 lanes, so n = 2 there would pad the
+# whole particle buffer 64-fold (tens of GB at 16 Mi slots per species).
+
+
 def _split_queues(st: StackedSpecies, n: int) -> list[StackedSpecies]:
     """Interleaved queue views: slot c -> queue c % n (keeps a compacted
-    live block evenly spread across queues)."""
+    live block evenly spread across queues), as strided slices."""
     if n == 1:
         return [st]
-
-    def sp(a):
-        s, cap = a.shape[:2]
-        return a.reshape((s, cap // n, n) + a.shape[2:])
-
-    parts = jax.tree.map(sp, st)
-    return [jax.tree.map(lambda a: a[:, :, k], parts) for k in range(n)]
+    return [jax.tree.map(lambda a: a[:, k::n], st) for k in range(n)]
 
 
 def _merge_queues(queues: list, n: int):
-    """Inverse of ``_split_queues`` (works on any matching pytrees)."""
+    """Inverse of ``_split_queues`` (works on any matching pytrees): the
+    queues laid end to end, then one gather back into slot order."""
     if n == 1:
         return queues[0]
 
     def mg(*xs):
-        stacked = jnp.stack(xs, axis=2)          # (S, capq, n, ...)
-        s, capq = stacked.shape[:2]
-        return stacked.reshape((s, capq * n) + stacked.shape[3:])
+        capq = xs[0].shape[1]
+        c = jnp.arange(capq * n)
+        return jnp.take(jnp.concatenate(xs, axis=1),
+                        (c % n) * capq + c // n, axis=1)
 
     return jax.tree.map(mg, *queues)
 
 
 def _queue_occupancy(alive: Array, n: int) -> Array:
     """(cap,) alive mask -> (n,) per-queue alive counts (slot c -> c % n)."""
-    return jnp.sum(alive.reshape(-1, n).astype(jnp.int32), axis=0)
+    return jnp.stack([jnp.sum(alive[k::n].astype(jnp.int32))
+                      for k in range(n)])
 
 
 def _exchange_queue(q, l_local: float, m: int, boundary: str,
@@ -469,18 +471,16 @@ def _inject_rows(full: SpeciesBuffer, cand: SpeciesBuffer):
 
 
 def _flush_pending(st: StackedSpecies, p: PendingArrivals) -> StackedSpecies:
-    """Scatter pre-claimed arrivals into their ring-assigned slots (vmapped
-    over the species axis). The slots were dead when claimed and nothing
-    re-fills slots between merge and ingest, so this is exact."""
-
-    def one(bx, bv, bw, ba, d, cx, cv, cw, ca):
-        out = inject_at(SpeciesBuffer(x=bx, v=bv, w=bw, alive=ba),
-                        d, cx, cv, cw, ca)
-        return out.x, out.v, out.w, out.alive
-
-    x, v, w, alive = jax.vmap(one)(st.x, st.v, st.w, st.alive,
-                                   p.dest, p.x, p.v, p.w, p.alive)
-    return StackedSpecies(x=x, v=v, w=w, alive=alive)
+    """Scatter pre-claimed arrivals into their ring-assigned slots, one
+    species at a time. The slots were dead when claimed and nothing re-fills
+    slots between merge and ingest, so this is exact. (A scatter vmapped
+    over the species axis computes the same, but at 16 Mi slots per species
+    it took the TPU compiler minutes where this loop takes seconds.)"""
+    return stack_species([
+        inject_at(SpeciesBuffer(x=st.x[j], v=st.v[j], w=st.w[j],
+                                alive=st.alive[j]),
+                  p.dest[j], p.x[j], p.v[j], p.w[j], p.alive[j])
+        for j in range(st.num_species)])
 
 
 def _empty_pending(s: int, m: int, cap: int, dtype) -> PendingArrivals:
@@ -986,7 +986,7 @@ def make_engine_step(ecfg: EngineConfig, mesh: Mesh, *, upto: str = "full",
                         # leavers were deposited at their raw (edge-clipped)
                         # positions by the in-pass deposit; take them back
                         # out
-                        rho_acc = rho_push - deposit_windowed(
+                        rho_acc = rho_push - deposit_flat(
                             grid_local, lv_x, charges[:, None] * lv_w)
                     if use_ring:
                         # leaver slots are free from here on: feed the ring
@@ -1077,7 +1077,7 @@ def make_engine_step(ecfg: EngineConfig, mesh: Mesh, *, upto: str = "full",
                         (cand.alive & ~accepted).astype(jnp.int32), axis=1)
                     write_back(idxs, full)
                     if carried:
-                        rho_acc = rho_acc + deposit_windowed(
+                        rho_acc = rho_acc + deposit_flat(
                             grid_local, pend_g.x,
                             charges[:, None] * pend_g.w * pend_g.alive)
                 else:
@@ -1090,7 +1090,7 @@ def make_engine_step(ecfg: EngineConfig, mesh: Mesh, *, upto: str = "full",
                     merged, dropped, accepted = _inject_rows(full, cand_all)
                     write_back(idxs, merged)
                     if carried:
-                        rho_acc = rho_acc + deposit_windowed(
+                        rho_acc = rho_acc + deposit_flat(
                             grid_local, cand_all.x,
                             charges[:, None] * cand_all.w * accepted)
                 for j, sc in enumerate(scs):
@@ -1152,11 +1152,11 @@ def make_engine_step(ecfg: EngineConfig, mesh: Mesh, *, upto: str = "full",
         # reads the same scalars, nothing is ever sharded or donated
         rp_specs = jax.tree.map(lambda _: P(),
                                 RuntimeParams.from_config(cfg))
-        step = halo.shard_map(
+        step = jax.shard_map(
             local_step, mesh=mesh, in_specs=(specs_state, rp_specs),
             out_specs=out_specs, check_vma=False)
         return jax.jit(step, **donate_kw)
-    step = halo.shard_map(
+    step = jax.shard_map(
         lambda estate: local_step(estate), mesh=mesh,
         in_specs=(specs_state,), out_specs=out_specs,
         check_vma=False)
@@ -1170,10 +1170,12 @@ def _engine_extras(ecfg: EngineConfig, mesh: Mesh, bufs):
     prows = _group_pending_rows(ecfg, groups)
     rings, pending = [], []
     for g, idxs in enumerate(groups):
-        st = stack_species([bufs[i] for i in idxs])
-        rings.append(jax.vmap(ring_init)(st.alive))
+        # one ring per species, stacked (not vmapped: see _flush_pending)
+        rings.append(jax.tree.map(lambda *a: jnp.stack(a),
+                                  *[ring_init(bufs[i].alive) for i in idxs]))
         pending.append(_empty_pending(
-            len(idxs), prows[g], st.capacity, st.x.dtype))
+            len(idxs), prows[g], bufs[idxs[0]].capacity,
+            bufs[idxs[0]].x.dtype))
     return tuple(rings), tuple(pending)
 
 
@@ -1196,8 +1198,8 @@ def attach_engine_state(ecfg: EngineConfig, mesh: Mesh,
             pending=tuple(_lift_tree(p) for p in pending))
 
     specs = _state_specs(ecfg, mesh)
-    f = halo.shard_map(local, mesh=mesh, in_specs=(specs.pic,),
-                       out_specs=specs, check_vma=False)
+    f = jax.shard_map(local, mesh=mesh, in_specs=(specs.pic,),
+                      out_specs=specs, check_vma=False)
     return jax.jit(f)(state)
 
 
@@ -1251,9 +1253,9 @@ def retarget_state(old: EngineConfig, new: EngineConfig, mesh: Mesh,
             pic=pic_out, rings=tuple(_lift_tree(rg) for rg in rings),
             pending=tuple(_lift_tree(p) for p in pending))
 
-    f = halo.shard_map(local, mesh=mesh,
-                       in_specs=(_state_specs(old, mesh),),
-                       out_specs=_state_specs(new, mesh), check_vma=False)
+    f = jax.shard_map(local, mesh=mesh,
+                      in_specs=(_state_specs(old, mesh),),
+                      out_specs=_state_specs(new, mesh), check_vma=False)
     return jax.jit(f)(state)
 
 
@@ -1299,8 +1301,8 @@ def init_engine_state(ecfg: EngineConfig, mesh: Mesh,
             pending=tuple(_lift_tree(p) for p in pending))
 
     specs_state = _state_specs(ecfg, mesh)
-    init = halo.shard_map(local_init, mesh=mesh, in_specs=(),
-                          out_specs=specs_state, check_vma=False)
+    init = jax.shard_map(local_init, mesh=mesh, in_specs=(),
+                         out_specs=specs_state, check_vma=False)
     return jax.jit(init)()
 
 
@@ -1473,6 +1475,6 @@ def elastic_state(ecfg: EngineConfig, mesh: Mesh, species, counts,
     part = P(ecfg.axis_names)
     in_specs = (tuple(SpeciesBuffer(x=part, v=part, w=part, alive=part)
                       for _ in bufs_in), part, P())
-    f = halo.shard_map(local, mesh=mesh, in_specs=in_specs,
-                       out_specs=_state_specs(ecfg, mesh), check_vma=False)
+    f = jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                      out_specs=_state_specs(ecfg, mesh), check_vma=False)
     return jax.jit(f)(bufs_in, counts_in, key_in)

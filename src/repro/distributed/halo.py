@@ -29,46 +29,20 @@ one-sided wall stencils take over there).
 
 from __future__ import annotations
 
-import inspect
-
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 
 from repro.obs import tracing
 
-try:                                   # jax >= 0.6: public top-level API
-    from jax import shard_map as _shard_map_impl
-except ImportError:                    # jax 0.4.x: experimental namespace
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-# the replication-checking kwarg was renamed check_rep -> check_vma; probe the
-# installed signature once and translate so call sites stay version-agnostic
-_SHARD_MAP_CHECK_KW = (
-    "check_vma"
-    if "check_vma" in inspect.signature(_shard_map_impl).parameters
-    else "check_rep")
-
 Array = jax.Array
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    kw = {_SHARD_MAP_CHECK_KW: check_vma}
-    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **kw)
-
-
-def axis_size(a: str):
-    if hasattr(jax.lax, "axis_size"):        # jax >= 0.5
-        return jax.lax.axis_size(a)
-    return jax.lax.psum(1, a)                # 0.4.x: psum of 1 == axis size
 
 
 def rank(axis_names) -> Array:
     """Linearized domain index over possibly-multiple mesh axes."""
     r = jnp.zeros((), jnp.int32)
     for a in axis_names:
-        r = r * axis_size(a) + jax.lax.axis_index(a)
+        r = r * jax.lax.axis_size(a) + jax.lax.axis_index(a)
     return r
 
 

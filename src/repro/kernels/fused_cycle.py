@@ -1,4 +1,4 @@
-"""Pallas TPU kernel: single-pass fused PIC cycle (gather + push + deposit).
+"""Pallas TPU kernel: single-pass fused PIC cycle (push + deposit).
 
 The PIC hot loop reads every particle twice per step: once to move it, once
 to deposit its charge for the next field solve. Hariri et al. 2016 fuse
@@ -6,19 +6,23 @@ gather/push/deposit into one pass over the particle list; this kernel is the
 TPU form of that fusion. Each grid step stages one particle tile HBM->VMEM
 (double-buffered by the Pallas pipeline), moves it, and deposits its
 POST-push charge into a (1, ng_pad) accumulator that stays VMEM-resident
-across all grid steps (constant index_map) — so particle arrays make exactly
-ONE HBM round-trip per cycle and the field sees exactly one (ng,) write.
+across all grid steps (constant index_map) — so the particle state makes
+ONE HBM round-trip through the kernel per cycle (the XLA-side field gather
+reads x once more) and the field sees exactly one (ng,) write.
 
 Layout contract (see ``core/particles.py``): particle arrays arrive as
 (rows, 128) planes — SoA with x, vx, vy, vz, alive, w each its own plane,
-VREG-aligned tiles of ``tile_rows`` sublanes. The node field E is resident
-in VMEM for the whole launch. Dead particles carry alive == 0 AND w == 0, so
-they feel no field and deposit no charge; pad slots are dead by construction.
+VREG-aligned tiles of ``tile_rows`` sublanes. The CIC field at each
+particle arrives as one more plane, ``ex``, gathered by XLA in
+``ops.fused_push_deposit``: Mosaic lowers no data-dependent gather from a
+1-D VMEM ref. Dead particles carry alive == 0 AND w == 0, so they feel no
+field and deposit no charge; pad slots are dead by construction.
 
 The deposit itself is the per-tile one-hot reduction of ``deposit.py``
 (broadcast/compare/reduce on the VPU — no data-dependent addressing), done
 sublane row by sublane row over the freshly-pushed positions while the tile
-is still on-chip.
+is still on-chip. Its width limit is ``MAX_NG_PAD``; with
+``do_deposit=False`` the kernel has none.
 """
 
 from __future__ import annotations
@@ -29,18 +33,23 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.deposit import onehot_rows
+
 Array = jax.Array
 
 LANES = 128
 
+# widest padded node axis for which the kernel compiles on TPU v5e with
+# do_deposit=True (see deposit.py); without the deposit there is no limit
+MAX_NG_PAD = 14208
 
-def _fused_kernel(x_ref, vx_ref, vy_ref, vz_ref, alive_ref, w_ref, e_ref,
+
+def _fused_kernel(x_ref, vx_ref, vy_ref, vz_ref, alive_ref, w_ref, ex_ref,
                   rho0_ref, xo_ref, vxo_ref, vyo_ref, vzo_ref, ao_ref,
                   hl_ref, hr_ref, wo_ref, rho_ref, *, x0: float, dx: float,
                   nc: int, length: float, qm_dt: float, dt: float,
                   charge: float, b: tuple[float, float, float],
-                  boundary: str, tile_rows: int, ng_pad: int,
-                  do_deposit: bool):
+                  boundary: str, ng_pad: int, do_deposit: bool):
     @pl.when(pl.program_id(0) == 0)
     def _init():
         # the VMEM accumulator starts from rho0 (zeros normally): a raw-
@@ -53,15 +62,7 @@ def _fused_kernel(x_ref, vx_ref, vy_ref, vz_ref, alive_ref, w_ref, e_ref,
     vx, vy, vz = vx_ref[...], vy_ref[...], vz_ref[...]
     alive = alive_ref[...]                      # float32 0/1 mask
     w = w_ref[...]
-
-    # ---- field gather (CIC) from the VMEM-resident node field ----
-    s = (x - x0) / dx
-    i = jnp.clip(jnp.floor(s).astype(jnp.int32), 0, nc - 1)
-    f = jnp.clip(s - i.astype(x.dtype), 0.0, 1.0)
-    e = e_ref[0, :]                             # (ng_pad,)
-    e_l = jnp.take(e, i, axis=0)
-    e_r = jnp.take(e, i + 1, axis=0)
-    e_x = (e_l * (1.0 - f) + e_r * f) * alive   # dead particles feel no field
+    e_x = ex_ref[...] * alive                   # dead particles feel no field
 
     # ---- Boris push (half kick, rotate, half kick) ----
     half = 0.5 * qm_dt
@@ -115,29 +116,21 @@ def _fused_kernel(x_ref, vx_ref, vy_ref, vz_ref, alive_ref, w_ref, e_ref,
     # field-solve-off benchmark scenario) — the rho output stays zero.
     if not do_deposit:
         return
-    sd = (xn - x0) / dx
-    di = jnp.clip(jnp.floor(sd).astype(jnp.int32), 0, nc - 1)
-    df = jnp.clip(sd - di.astype(x.dtype), 0.0, 1.0)
-    q = charge * wn
-    acc = jnp.zeros((ng_pad,), rho_ref.dtype)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (LANES, ng_pad), 1)
-    for r in range(tile_rows):
-        ir, fr, qr = di[r, :], df[r, :], q[r, :]
-        left = jnp.where(cols == ir[:, None], (qr * (1.0 - fr))[:, None], 0.0)
-        right = jnp.where(cols == (ir + 1)[:, None], (qr * fr)[:, None], 0.0)
-        acc = acc + jnp.sum(left + right, axis=0)
+    acc = onehot_rows(xn, charge * wn, x0=x0, dx=dx, nc=nc, ng_pad=ng_pad)
     rho_ref[...] += acc[None, :].astype(rho_ref.dtype)
 
 
 def fused_push_deposit_pallas(x: Array, vx: Array, vy: Array, vz: Array,
-                              alive_f: Array, w: Array, e_pad: Array,
+                              alive_f: Array, w: Array, ex: Array,
                               rho0_pad: Array | None = None, *,
-                              x0: float, dx: float, nc: int, length: float,
-                              qm: float, dt: float, charge: float,
+                              x0: float, dx: float, nc: int, ng_pad: int,
+                              length: float, qm: float, dt: float,
+                              charge: float,
                               b: tuple[float, float, float], boundary: str,
                               tile_rows: int = 8, interpret: bool = True,
                               do_deposit: bool = True):
-    """Launch the fused cycle. All particle planes are (rows, 128).
+    """Launch the fused cycle. All particle planes, ``ex`` (the field at
+    each particle) included, are (rows, 128).
 
     Returns (xn, vxn, vyn, vzn, alive_n, hit_l, hit_r, wn, rho) where rho is
     the (1, ng_pad) node charge (times dx — the caller divides, matching
@@ -147,26 +140,25 @@ def fused_push_deposit_pallas(x: Array, vx: Array, vy: Array, vz: Array,
     rows = x.shape[0]
     assert rows % tile_rows == 0, (rows, tile_rows)
     grid = (rows // tile_rows,)
-    ng_pad = e_pad.shape[1]
     if rho0_pad is None:
         rho0_pad = jnp.zeros((1, ng_pad), x.dtype)
 
     tile = pl.BlockSpec((tile_rows, LANES), lambda r: (r, 0))
-    field = pl.BlockSpec((1, ng_pad), lambda r: (0, 0))  # VMEM-resident
+    acc = pl.BlockSpec((1, ng_pad), lambda r: (0, 0))  # VMEM-resident
 
     kernel = functools.partial(
         _fused_kernel, x0=x0, dx=dx, nc=nc, length=length, qm_dt=qm * dt,
-        dt=dt, charge=charge, b=b, boundary=boundary, tile_rows=tile_rows,
-        ng_pad=ng_pad, do_deposit=do_deposit)
+        dt=dt, charge=charge, b=b, boundary=boundary, ng_pad=ng_pad,
+        do_deposit=do_deposit)
 
     out_shape = ([jax.ShapeDtypeStruct((rows, LANES), x.dtype)] * 8
                  + [jax.ShapeDtypeStruct((1, ng_pad), x.dtype)])
     outs = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[tile] * 6 + [field, field],
-        out_specs=[tile] * 8 + [field],
+        in_specs=[tile] * 7 + [acc],
+        out_specs=[tile] * 8 + [acc],
         out_shape=out_shape,
         interpret=interpret,
-    )(x, vx, vy, vz, alive_f, w, e_pad, rho0_pad)
+    )(x, vx, vy, vz, alive_f, w, ex, rho0_pad)
     return outs

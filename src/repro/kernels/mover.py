@@ -1,4 +1,4 @@
-"""Pallas TPU kernel: fused particle mover (gather-E + Boris push + boundary).
+"""Pallas TPU kernel: fused particle mover (Boris push + boundary).
 
 This is the 'explicit data movement' strategy of the paper, adapted to TPU:
 instead of `#pragma acc enter data copyin(...)` staging whole arrays to GPU
@@ -9,9 +9,11 @@ k computes, which is precisely the overlap the paper gets from CUDA streams
 
 Layout: particle arrays are viewed as (rows, 128) planes (SoA: x, vx, vy, vz,
 alive each its own plane) so tiles are VREG-aligned (8x128 multiples). The
-node field E stays resident in VMEM across all grid steps (its BlockSpec
-index_map is constant), so the per-particle gather never touches HBM — this
-removes the 80%-memcpy bottleneck the paper profiles on the A100.
+CIC field at each particle arrives as one more plane, ``ex``: Mosaic lowers
+no data-dependent gather from a 1-D VMEM ref ("Only 2D gather is
+supported"), so ``ops.mover_push`` gathers E at the XLA level
+(``grid.gather``) and the kernel stays gather-free, which also leaves it
+with no limit on the grid width.
 
 Work per tile is uniform by construction (a tile is just 'the next TM*128
 particles'), which is the TPU-native answer to the per-cell load imbalance
@@ -21,7 +23,6 @@ BIT1 fights with OpenMP tasks.
 from __future__ import annotations
 
 import functools
-from typing import Literal
 
 import jax
 import jax.numpy as jnp
@@ -32,23 +33,14 @@ Array = jax.Array
 LANES = 128
 
 
-def _mover_kernel(x_ref, vx_ref, vy_ref, vz_ref, alive_ref, e_ref,
+def _mover_kernel(x_ref, vx_ref, vy_ref, vz_ref, alive_ref, ex_ref,
                   xo_ref, vxo_ref, vyo_ref, vzo_ref, ao_ref, hl_ref, hr_ref,
-                  *, x0: float, dx: float, nc: int, length: float,
-                  qm_dt: float, dt: float, b: tuple[float, float, float],
-                  boundary: str):
+                  *, length: float, qm_dt: float, dt: float,
+                  b: tuple[float, float, float], boundary: str):
     x = x_ref[...]
     vx, vy, vz = vx_ref[...], vy_ref[...], vz_ref[...]
     alive = alive_ref[...]                      # float32 0/1 mask
-
-    # ---- field gather (CIC) from the VMEM-resident node field ----
-    s = (x - x0) / dx
-    i = jnp.clip(jnp.floor(s).astype(jnp.int32), 0, nc - 1)
-    f = jnp.clip(s - i.astype(x.dtype), 0.0, 1.0)
-    e = e_ref[0, :]                             # (ng_pad,)
-    e_l = jnp.take(e, i, axis=0)
-    e_r = jnp.take(e, i + 1, axis=0)
-    e_x = (e_l * (1.0 - f) + e_r * f) * alive   # dead particles feel no field
+    e_x = ex_ref[...] * alive                   # dead particles feel no field
 
     # ---- Boris push (half kick, rotate, half kick) ----
     half = 0.5 * qm_dt
@@ -97,31 +89,27 @@ def _mover_kernel(x_ref, vx_ref, vy_ref, vz_ref, alive_ref, e_ref,
 
 
 def mover_push_pallas(x: Array, vx: Array, vy: Array, vz: Array,
-                      alive_f: Array, e_pad: Array, *, x0: float, dx: float,
-                      nc: int, length: float, qm: float, dt: float,
-                      b: tuple[float, float, float], boundary: str,
+                      alive_f: Array, ex: Array, *, length: float, qm: float,
+                      dt: float, b: tuple[float, float, float], boundary: str,
                       tile_rows: int = 8, interpret: bool = True):
-    """Launch the fused mover. All particle planes are (rows, 128)."""
+    """Launch the fused mover. All inputs, ``ex`` (the field at each
+    particle) included, are (rows, 128) planes."""
     rows = x.shape[0]
     assert rows % tile_rows == 0, (rows, tile_rows)
     grid = (rows // tile_rows,)
-    ng_pad = e_pad.shape[1]
-
     tile = pl.BlockSpec((tile_rows, LANES), lambda r: (r, 0))
-    field = pl.BlockSpec((1, ng_pad), lambda r: (0, 0))  # VMEM-resident
 
-    qm_dt = qm * dt
     kernel = functools.partial(
-        _mover_kernel, x0=x0, dx=dx, nc=nc, length=length, qm_dt=qm_dt,
-        dt=dt, b=b, boundary=boundary)
+        _mover_kernel, length=length, qm_dt=qm * dt, dt=dt, b=b,
+        boundary=boundary)
 
     out_shape = [jax.ShapeDtypeStruct((rows, LANES), x.dtype)] * 7
     outs = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[tile, tile, tile, tile, tile, field],
+        in_specs=[tile] * 6,
         out_specs=[tile] * 7,
         out_shape=out_shape,
         interpret=interpret,
-    )(x, vx, vy, vz, alive_f, e_pad)
+    )(x, vx, vy, vz, alive_f, ex)
     return outs

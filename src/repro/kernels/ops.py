@@ -3,9 +3,15 @@
 Dtype plumbing and backend selection live here; the planar
 (cap,) <-> (rows, 128) relayout contract lives in ``core/particles.py``
 (``to_planes`` / ``from_planes``), shared with the buffers themselves so the
-layout is defined exactly once. On CPU/GPU backends the kernels run in
-interpret mode (Python evaluation of the kernel body — the validation mode
-for this container); on TPU they compile through Mosaic.
+layout is defined exactly once. On the CPU backend the kernels run in
+interpret mode (Python evaluation of the kernel body — how the tests check
+them); on TPU they always compile through Mosaic, and a width the compiler
+refuses raises ``deposit.KernelWidthError`` here, at trace time. Any other
+backend is refused.
+
+The mover and the fused cycle take the CIC field at each particle as a
+plane gathered here by XLA (``grid.gather``): Mosaic lowers no
+data-dependent gather from a 1-D VMEM ref.
 """
 
 from __future__ import annotations
@@ -15,7 +21,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.core.particles import LANES, from_planes, plane_pad, to_planes
+from repro.core.grid import Grid1D, gather
+from repro.core.particles import LANES, from_planes, to_planes
 from repro.kernels import collide as _collide
 from repro.kernels import deposit as _deposit
 from repro.kernels import fused_cycle as _fused
@@ -25,7 +32,22 @@ Array = jax.Array
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """True on the CPU backend, False on TPU; any other backend raises."""
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(
+            f"the Pallas kernels compile for TPU or run interpreted on CPU; "
+            f"backend {backend!r} is neither")
+    return backend == "cpu"
+
+
+def _ng_pad(ng: int) -> int:
+    return ng + (-ng) % LANES
+
+
+def _field_plane(x: Array, e: Array, x0: float, dx: float, nc: int,
+                 tile_rows: int) -> Array:
+    return to_planes(gather(Grid1D(nc=nc, dx=dx, x0=x0), e, x), tile_rows)
 
 
 def _particle_planes(x: Array, v: Array, alive: Array, tile_rows: int):
@@ -45,16 +67,15 @@ def mover_push(x: Array, v: Array, alive: Array, e: Array, *, x0: float,
 
     Returns (x, v, alive, hit_left, hit_right) with original shapes.
     """
-    del gather_mode  # in-kernel gather is jnp.take; onehot lives at XLA level
+    del gather_mode  # the field gather is grid.gather at the XLA level
     cap = x.shape[0]
     nc = round(length / dx)
     xp, vxp, vyp, vzp, ap = _particle_planes(x, v, alive, tile_rows)
-    ep = plane_pad(e, LANES)[None, :]
+    exp = _field_plane(x, e, x0, dx, nc, tile_rows)
 
     xn, vxn, vyn, vzn, an, hl, hr = _mover.mover_push_pallas(
-        xp, vxp, vyp, vzp, ap, ep, x0=x0, dx=dx, nc=nc, length=length,
-        qm=qm, dt=dt, b=b, boundary=boundary, tile_rows=tile_rows,
-        interpret=_interpret())
+        xp, vxp, vyp, vzp, ap, exp, length=length, qm=qm, dt=dt, b=b,
+        boundary=boundary, tile_rows=tile_rows, interpret=_interpret())
 
     def unpad(p):
         return from_planes(p, cap)
@@ -89,14 +110,19 @@ def fused_push_deposit(x: Array, v: Array, alive: Array, w: Array, e: Array,
     cap = x.shape[0]
     nc = round(length / dx)
     ng = e.shape[0]
+    interpret = _interpret()
+    if deposit and not interpret:
+        _deposit.check_width("fused_push_deposit", _ng_pad(ng),
+                             _fused.MAX_NG_PAD)
     xp, vxp, vyp, vzp, ap = _particle_planes(x, v, alive, tile_rows)
     wp = to_planes(w, tile_rows)
-    ep = plane_pad(e, LANES)[None, :]
+    exp = _field_plane(x, e, x0, dx, nc, tile_rows)
 
     xn, vxn, vyn, vzn, an, hl, hr, wn, rho = _fused.fused_push_deposit_pallas(
-        xp, vxp, vyp, vzp, ap, wp, ep, None, x0=x0, dx=dx, nc=nc,
-        length=length, qm=qm, dt=dt, charge=charge, b=b, boundary=boundary,
-        tile_rows=tile_rows, interpret=_interpret(), do_deposit=deposit)
+        xp, vxp, vyp, vzp, ap, wp, exp, None, x0=x0, dx=dx, nc=nc,
+        ng_pad=_ng_pad(ng), length=length, qm=qm, dt=dt, charge=charge, b=b,
+        boundary=boundary, tile_rows=tile_rows, interpret=interpret,
+        do_deposit=deposit)
 
     def unpad(p):
         return from_planes(p, cap)
@@ -146,9 +172,11 @@ def flash_attention(q: Array, k: Array, v: Array, *, causal: bool = True,
 def deposit(x: Array, q: Array, *, x0: float, dx: float, nc: int,
             ng: int) -> Array:
     """CIC deposition of per-particle charge q at positions x -> (ng,)/dx."""
-    xp = to_planes(x, 1)
-    qp = to_planes(q, 1)                     # padded q == 0 -> no deposit
-    ng_pad = ng + ((-ng) % LANES)
-    rho = _deposit.deposit_pallas(xp, qp, x0=x0, dx=dx, nc=nc, ng_pad=ng_pad,
-                                  interpret=_interpret())
+    interpret = _interpret()
+    if not interpret:
+        _deposit.check_width("deposit", _ng_pad(ng), _deposit.MAX_NG_PAD)
+    xp = to_planes(x)
+    qp = to_planes(q)                        # padded q == 0 -> no deposit
+    rho = _deposit.deposit_pallas(xp, qp, x0=x0, dx=dx, nc=nc,
+                                  ng_pad=_ng_pad(ng), interpret=interpret)
     return rho[0, :ng] / dx

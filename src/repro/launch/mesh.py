@@ -20,29 +20,15 @@ import math
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
-
-try:                       # jax >= 0.5 exposes explicit-mode axis types
-    from jax.sharding import AxisType
-except ImportError:        # jax 0.4.x: meshes are implicitly Auto everywhere
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
-def _axis_type_kw(n_axes: int) -> dict:
-    """axis_types kwarg for Mesh/make_mesh, empty on jax versions without it."""
-    if AxisType is None:
-        return {}
-    return {"axis_types": (AxisType.Auto,) * n_axes}
+def _auto(axes) -> tuple[AxisType, ...]:
+    return (AxisType.Auto,) * len(axes)
 
 
 def _make_mesh(shape, axes) -> Mesh:
-    if hasattr(jax, "make_mesh"):
-        try:
-            return jax.make_mesh(shape, axes, **_axis_type_kw(len(axes)))
-        except TypeError:  # make_mesh predates the axis_types kwarg
-            return jax.make_mesh(shape, axes)
-    grid = np.asarray(jax.devices()[:math.prod(shape)]).reshape(shape)
-    return Mesh(grid, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -56,7 +42,7 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     # takes the first 256
     assert len(devs) >= need, (len(devs), need)
     grid = np.asarray(devs[:need]).reshape(shape)
-    return Mesh(grid, axes, **_axis_type_kw(len(axes)))
+    return Mesh(grid, axes, axis_types=_auto(axes))
 
 
 def make_debug_mesh(data: int = 1, model: int = 1, pod: int = 0) -> Mesh:

@@ -25,7 +25,9 @@ exchange; --cell-order makes the rebalance a BIT1-style counting sort by
 cell so the queue slices stay cell-striped. If the process exposes
 fewer jax devices than --domains, emulated host devices are requested via
 XLA_FLAGS before jax initializes (a TPU slice provides real ones
-natively). --phases prints the per-phase timing breakdown.
+natively); the first line printed names the platform, device kind and
+device count the run used. --phases prints the per-phase timing
+breakdown.
 
 Observability (``repro.obs``): --profile-dir DIR captures a profiler trace
 of the run (``jax.profiler.start_trace``; open in TensorBoard/Perfetto —
@@ -137,7 +139,8 @@ def main() -> None:
 
     if args.domains > 1:
         # must happen before jax initializes; a no-op when XLA_FLAGS is
-        # already set (e.g. a real TPU slice or an outer test harness)
+        # already set (e.g. a real TPU slice or an outer test harness).
+        # It only adds host devices: on TPU the mesh takes the chips.
         os.environ.setdefault(
             "XLA_FLAGS",
             f"--xla_force_host_platform_device_count={args.domains}")
@@ -146,6 +149,13 @@ def main() -> None:
 
     import jax
     import numpy as np
+
+    from repro.launch.cache import enable_compilation_cache
+
+    enable_compilation_cache()
+    devs = jax.devices()
+    print(f"device: platform={devs[0].platform} "
+          f"kind={devs[0].device_kind} count={len(devs)}")
 
     from repro.configs.pic_bit1 import (make_bench_config,
                                         make_collision_menu,

@@ -97,7 +97,7 @@ def trace_session(profile_dir: str | None) -> Iterator[None]:
 
 
 def _jaxpr_of(obj):
-    from jax.core import ClosedJaxpr, Jaxpr  # stable across 0.4.x..0.6.x
+    from jax.extend.core import ClosedJaxpr, Jaxpr
     if isinstance(obj, ClosedJaxpr):
         return obj.jaxpr
     if isinstance(obj, Jaxpr):

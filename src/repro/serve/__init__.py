@@ -12,10 +12,10 @@ from repro.serve.ensemble import (EnsembleState, init_ensemble,
                                   make_ensemble_step, make_member_init,
                                   make_member_insert, make_member_release,
                                   member_view)
-from repro.serve.service import SimService, enable_compilation_cache
+from repro.serve.service import SimService
 
 __all__ = [
     "EnsembleState", "init_ensemble", "make_ensemble_step",
     "make_member_init", "make_member_insert", "make_member_release",
-    "member_view", "SimService", "enable_compilation_cache",
+    "member_view", "SimService",
 ]

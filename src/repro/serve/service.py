@@ -13,10 +13,11 @@ at one parameter point:
 
 Everything on the hot path is compiled exactly once per (config, width):
 member init takes the seed traced, insert takes the slot traced, the step
-takes every runtime scalar traced. ``enable_compilation_cache`` points JAX's
-persistent compilation cache at a directory so NEW worker processes start
-hot — the profiling companion papers show compile/setup dominating short
-runs, which is exactly the cost this removes.
+takes every runtime scalar traced. A worker process that calls
+``repro.launch.cache.enable_compilation_cache`` before building the service
+starts hot from JAX's persistent compilation cache — the profiling
+companion papers show compile/setup dominating short runs, which is
+exactly the cost this removes.
 """
 
 from __future__ import annotations
@@ -24,28 +25,12 @@ from __future__ import annotations
 import collections
 import dataclasses
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core import pic
 from repro.core.params import RuntimeParams, runtime_params
 from repro.serve import ensemble
-
-
-def enable_compilation_cache(path: str) -> None:
-    """Point JAX's persistent compilation cache at ``path``.
-
-    Compiled executables are written to disk and re-read by any later
-    process with the same config/topology — a fresh serving worker skips
-    straight past compilation. The min-compile-time floor is dropped to 0
-    so even fast-compiling steps (smoke configs) are cached.
-    """
-    jax.config.update("jax_compilation_cache_dir", str(path))
-    try:
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    except AttributeError:  # older jax spelling; cache still works
-        pass
 
 
 @dataclasses.dataclass
@@ -69,10 +54,7 @@ class SimService:
     a submit may vary only runtime parameters (see ``core/params.py``).
     """
 
-    def __init__(self, cfg: pic.PICConfig, width: int = 4,
-                 cache_dir: str | None = None):
-        if cache_dir is not None:
-            enable_compilation_cache(cache_dir)
+    def __init__(self, cfg: pic.PICConfig, width: int = 4):
         self.cfg = cfg
         self.width = width
         self._step = ensemble.make_ensemble_step(cfg)

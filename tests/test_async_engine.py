@@ -134,20 +134,27 @@ def test_async_n_must_divide_budget_and_capacity():
 
 def check_domain_parity():
     """D in {1, 2, 4} x async_n in {1, 2, 4}, with and without queue
-    rebalancing: particle count and total charge must match the synchronous
-    D=1 reference EXACTLY (conservation — including across rebalance_every
-    boundaries); kinetic energy statistically (domains draw independent
-    samples)."""
+    rebalancing: particle count must match the synchronous D=1 reference
+    EXACTLY (conservation — including across rebalance_every boundaries).
+    Total charge is an f32 sum whose last bits depend on the reduction
+    order, which differs with the layout: it must agree within the pairwise
+    summation bound log2(count) * count * weight * eps — far below the
+    charge of one lost particle. Kinetic energy agrees statistically
+    (domains draw independent samples)."""
     cfg = _cfg()
     ref, ref_sums = _run(cfg, 1, 1, 20)
+    eps = float(np.finfo(np.float32).eps)
     for d, an, reb in [(2, 1, 0), (2, 2, 0), (4, 1, 0), (4, 4, 0),
                        (1, 2, 3), (2, 2, 3), (4, 4, 3)]:
         diag, sums = _run(cfg, d, an, 20, rebalance_every=reb)
         for sc in cfg.species:
-            assert diag[f"{sc.name}/count"] == ref[f"{sc.name}/count"], (
-                d, an, reb, sc.name)
-            assert diag[f"{sc.name}/charge"] == ref[f"{sc.name}/charge"], (
-                d, an, reb, sc.name)
+            count = diag[f"{sc.name}/count"]
+            assert count == ref[f"{sc.name}/count"], (d, an, reb, sc.name)
+            tol = np.ceil(np.log2(count)) * count * sc.weight * eps
+            assert sc.weight > tol
+            np.testing.assert_allclose(
+                diag[f"{sc.name}/charge"], ref[f"{sc.name}/charge"], rtol=0,
+                atol=tol, err_msg=str((d, an, reb, sc.name)))
             np.testing.assert_allclose(
                 diag[f"{sc.name}/ke"], ref[f"{sc.name}/ke"], rtol=0.15)
             assert sums[f"{sc.name}/migration_overflow"] == 0
@@ -252,8 +259,8 @@ def check_halo_field_matches_global():
             mesh=mesh, is_first=r == 0, is_last=r == d - 1)
         return e[None]
 
-    f = halo.shard_map(local, mesh=mesh, in_specs=(P("data"),),
-                       out_specs=P("data"), check_vma=False)
+    f = jax.shard_map(local, mesh=mesh, in_specs=(P("data"),),
+                      out_specs=P("data"), check_vma=False)
     e_loc = np.asarray(jax.jit(f)(jnp.asarray(locs)))
     e_ref = np.asarray(fields.efield(fields.solve_poisson(
         fields.smooth_binomial(jnp.asarray(rho_g), 2), 1.0), 1.0))

@@ -7,13 +7,16 @@ running/done status with per-session diagnostics.
 """
 
 import dataclasses
+import os
+from pathlib import Path
 
 import numpy as np
 import pytest
 
 from repro.configs import pic_bit1
 from repro.core.params import runtime_params
-from repro.serve import SimService, enable_compilation_cache
+from repro.launch import cache
+from repro.serve import SimService
 
 import jax
 
@@ -67,10 +70,24 @@ def test_submit_validation():
         svc.submit({"nc": 128})
 
 
-def test_prebuilt_params_and_cache_dir(tmp_path):
+def test_prebuilt_params_and_cache_dir(tmp_path, monkeypatch):
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        # a directory from the environment stands: no code sets another
+        monkeypatch.setenv(cache.ENV, str(tmp_path))
+        assert cache.enable_compilation_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == prev
+        # unset: the fixed, git-ignored directory in the checkout, exported
+        # so that child processes share it
+        monkeypatch.delenv(cache.ENV)
+        want = str(cache.CHECKOUT_CACHE)
+        assert cache.enable_compilation_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+        assert os.environ[cache.ENV] == want
+        assert cache.CHECKOUT_CACHE.parent == Path(__file__).parents[1]
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
     cfg = _cfg()
-    enable_compilation_cache(str(tmp_path))
-    assert jax.config.jax_compilation_cache_dir == str(tmp_path)
     svc = SimService(cfg, width=1)
     sid = svc.submit(params=runtime_params(cfg, dt=0.25), steps=1)
     svc.run_until_drained()
