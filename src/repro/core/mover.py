@@ -46,6 +46,7 @@ import jax.numpy as jnp
 from repro.core.grid import (Grid1D, deposit_stacked, deposit_flat,
                              gather, gather_onehot)
 from repro.core.particles import SpeciesBuffer, StackedSpecies
+from repro.obs import tracing
 
 Array = jax.Array
 
@@ -124,17 +125,33 @@ def _wall_diag(v: Array, w: Array, hl: Array, hr: Array) -> dict:
     }
 
 
-def _push_core(x: Array, v: Array, alive: Array, e: Array, grid: Grid1D,
-               qm_dt: Array | float, dt: Array | float,
-               b: tuple[float, float, float], boundary: Boundary,
-               gather_mode: str):
-    """Gather + Boris + drift + boundary on raw arrays (vmap-friendly)."""
+def _field_at(x: Array, alive: Array, e: Array, grid: Grid1D,
+              gather_mode: str) -> Array:
+    """E at the particles (the CIC gather; zero on dead slots)."""
     g = gather_onehot if gather_mode == "onehot" else gather
-    e_x = g(grid, e, x) * alive
+    return g(grid, e, x) * alive
+
+
+def _move(x: Array, v: Array, alive: Array, e_x: Array, grid: Grid1D,
+          qm_dt: Array | float, dt: Array | float,
+          b: tuple[float, float, float], boundary: Boundary):
+    """Boris + drift + boundary on raw arrays, given E at the particles."""
     v = boris_kick(v, e_x, qm_dt, b)
     x = x + v[:, 0] * dt
     x, alive, hl, hr = apply_boundary(x, alive, grid.length, boundary)
     return x, v, alive, hl, hr
+
+
+def _push_core(x: Array, v: Array, alive: Array, e: Array, grid: Grid1D,
+               qm_dt: Array | float, dt: Array | float,
+               b: tuple[float, float, float], boundary: Boundary,
+               gather_mode: str):
+    """Gather + Boris + drift + boundary on raw arrays, under the
+    ``field_gather`` and ``move`` scopes."""
+    with tracing.phase_scope("field_gather"):
+        e_x = _field_at(x, alive, e, grid, gather_mode)
+    with tracing.phase_scope("move"):
+        return _move(x, v, alive, e_x, grid, qm_dt, dt, b, boundary)
 
 
 def push_unified(buf: SpeciesBuffer, e: Array, grid: Grid1D, qm: float,
@@ -212,9 +229,10 @@ def push_fused(buf: SpeciesBuffer, e: Array, grid: Grid1D, qm: float,
     w = buf.w * alive
     rho = None
     if deposit_charge is not None:
-        rho = deposit_flat(grid, x, deposit_charge * w)
-        if rho_carry is not None:
-            rho = rho_carry + rho
+        with tracing.phase_scope("deposit"):
+            rho = deposit_flat(grid, x, deposit_charge * w)
+            if rho_carry is not None:
+                rho = rho_carry + rho
     out = dataclasses.replace(buf, x=x, v=v, alive=alive, w=w)
     return PushResult(out, hl, hr, diag, rho)
 
@@ -288,20 +306,29 @@ def push_stacked(st: StackedSpecies, e: Array, grid: Grid1D, qm: Array,
 
     Returns (stacked, hit_left (S, cap), hit_right (S, cap),
     diag dict of (S,) arrays, rho | None).
-    """
-    def core(x, v, alive, qm_s, dt_s):
-        return _push_core(x, v, alive, e, grid, qm_s * dt_s, dt_s, b,
-                          boundary, gather_mode)
 
-    x, v, alive, hl, hr = jax.vmap(core)(st.x, st.v, st.alive, qm, dt)
-    diag = _wall_diag(v, st.w, hl, hr)          # reductions over axis=-1
-    w = st.w * alive
+    The scopes ``field_gather``, ``move`` and ``deposit`` open outside the
+    vmaps: a scope entered inside a vmapped function is named
+    ``vmap(<scope>)`` in the op_name.
+    """
+    with tracing.phase_scope("field_gather"):
+        e_x = jax.vmap(lambda x, alive: _field_at(x, alive, e, grid,
+                                                  gather_mode))(
+            st.x, st.alive)
+    with tracing.phase_scope("move"):
+        x, v, alive, hl, hr = jax.vmap(
+            lambda x, v, alive, e_x, qm_s, dt_s: _move(
+                x, v, alive, e_x, grid, qm_s * dt_s, dt_s, b, boundary))(
+            st.x, st.v, st.alive, e_x, qm, dt)
+        diag = _wall_diag(v, st.w, hl, hr)      # reductions over axis=-1
+        w = st.w * alive
     out = StackedSpecies(x=x, v=v, w=w, alive=alive)
     rho = None
     if charges is not None:
-        rho = deposit_stacked(grid, x, w, alive, charges)
-        if rho_carry is not None:
-            rho = rho_carry + rho
+        with tracing.phase_scope("deposit"):
+            rho = deposit_stacked(grid, x, w, alive, charges)
+            if rho_carry is not None:
+                rho = rho_carry + rho
     return out, hl, hr, diag, rho
 
 

@@ -669,13 +669,18 @@ def make_engine_step(ecfg: EngineConfig, mesh: Mesh, *, upto: str = "full",
 
     def local_step(estate: EngineState, rp: RuntimeParams | None = None):
         state = estate.pic
-        species = [jax.tree.map(lambda a: a[0], b) for b in state.species]
-        rings = [jax.tree.map(lambda a: a[0], r) for r in estate.rings]
-        pend_in = [jax.tree.map(lambda a: a[0], p) for p in estate.pending]
-        key = state.key[0]
-        r = halo.rank(axis_names)
-        is_first = r == 0
-        is_last = r == d - 1
+        # ---- the domain's state without its leading (1, ...) device axis,
+        #      and the domain's rank ----
+        with tracing.phase_scope("engine/state"):
+            species = [jax.tree.map(lambda a: a[0], b)
+                       for b in state.species]
+            rings = [jax.tree.map(lambda a: a[0], r) for r in estate.rings]
+            pend_in = [jax.tree.map(lambda a: a[0], p)
+                       for p in estate.pending]
+            key = state.key[0]
+            r = halo.rank(axis_names)
+            is_first = r == 0
+            is_last = r == d - 1
 
         def group_meta(idxs):
             scs = [cfg.species[i] for i in idxs]
@@ -694,10 +699,11 @@ def make_engine_step(ecfg: EngineConfig, mesh: Mesh, *, upto: str = "full",
                     alive=full.alive[j])
 
         def pack_state(rho, pend_out):
-            return EngineState(
-                pic=_lift(species, key, state.step + 1, rho),
-                rings=tuple(_lift_tree(rg) for rg in rings),
-                pending=tuple(_lift_tree(p) for p in pend_out))
+            with tracing.phase_scope("engine/state"):
+                return EngineState(
+                    pic=_lift(species, key, state.step + 1, rho),
+                    rings=tuple(_lift_tree(rg) for rg in rings),
+                    pending=tuple(_lift_tree(p) for p in pend_out))
 
         # ---- ingest: land last step's arrivals + births in their
         #      pre-claimed slots (the scatter deferred out of the merge
@@ -705,9 +711,10 @@ def make_engine_step(ecfg: EngineConfig, mesh: Mesh, *, upto: str = "full",
         #      rebalance_every steps, or whenever the post-flush per-queue
         #      occupancy skew exceeds rebalance_skew ----
         rebalance_periodic = None
-        if reb_k > 0:
-            rebalance_periodic = (state.step > 0) & (state.step % reb_k == 0)
         with tracing.phase_scope("engine/ingest"):
+            if reb_k > 0:
+                rebalance_periodic = ((state.step > 0)
+                                      & (state.step % reb_k == 0))
             for g, idxs in enumerate(groups):
                 cap_g = group_caps[g]
                 if not (use_ring or reb_k > 0 or skew_k > 0):
@@ -743,10 +750,13 @@ def make_engine_step(ecfg: EngineConfig, mesh: Mesh, *, upto: str = "full",
                             reb_g, lambda s: sort_group(s)[0],
                             lambda s: s, st)
                 write_back(idxs, st)
-        empty_pend = [
-            _empty_pending(len(idxs), prows[g], group_caps[g],
-                           species[idxs[0]].x.dtype)
-            for g, idxs in enumerate(groups)] if use_ring else []
+        with tracing.phase_scope("engine/state"):
+            empty_pend = [
+                _empty_pending(len(idxs), prows[g], group_caps[g],
+                               species[idxs[0]].x.dtype)
+                for g, idxs in enumerate(groups)] if use_ring else []
+            rho_acc = (jnp.zeros((ncl + 1,), jnp.float32) if carried
+                       else None)
         if upto == "ingest":
             aux = sum(jnp.sum(b.alive.astype(jnp.float32))
                       for b in species).reshape(1)
@@ -780,8 +790,6 @@ def make_engine_step(ecfg: EngineConfig, mesh: Mesh, *, upto: str = "full",
             key_ = f"{name}/{k}" if name else k
             diag[key_] = diag.get(key_, 0) + v
 
-        rho_acc = jnp.zeros((ncl + 1,), jnp.float32) if carried else None
-
         # ---- MC source inputs: one electron-density deposit (halo-summed
         #      at the shared edge nodes) and per-queue event keys, derived
         #      identically in ring and legacy modes so the two paths draw
@@ -795,9 +803,10 @@ def make_engine_step(ecfg: EngineConfig, mesh: Mesh, *, upto: str = "full",
                     rate=(cfg.ionization_rate if rp is None
                           else rp.ionization_rate),
                     vth_electron=cfg.ionization_vth_e)
-                ne_local = halo.halo_sum(
-                    deposit_density(grid_local, species[ion[1]]),
-                    axis_names, mesh, is_first, is_last)
+                with tracing.phase_scope("ne_deposit"):
+                    ne_local = halo.halo_sum(
+                        deposit_density(grid_local, species[ion[1]]),
+                        axis_names, mesh, is_first, is_last)
             if see_pairs:
                 eparams = boundaries.EmissionParams(
                     yield_=(cfg.emission_yield if rp is None
@@ -835,12 +844,16 @@ def make_engine_step(ecfg: EngineConfig, mesh: Mesh, *, upto: str = "full",
         staged = []
         birth_blocks: list[list] = [[] for _ in groups]
         for g, idxs in enumerate(groups):
-            scs, qm, dts, charges = group_meta(idxs)
-            strides = [sc.stride for sc in scs]
-            dtype = species[idxs[0]].x.dtype
-            st = stack_species([species[i] for i in idxs])
+            # ---- the group stacked and split into its interleaved queues
+            #      (strided views, which the TPU lowers to row gathers) ----
+            with tracing.phase_scope("engine/split"):
+                scs, qm, dts, charges = group_meta(idxs)
+                strides = [sc.stride for sc in scs]
+                dtype = species[idxs[0]].x.dtype
+                queues = _split_queues(
+                    stack_species([species[i] for i in idxs]), n_q)
             kept_qs, pending_packs = [], []
-            for k_q, q in enumerate(_split_queues(st, n_q)):
+            for k_q, q in enumerate(queues):
                 with tracing.phase_scope(f"engine/push/q{k_q}"):
                     out, hl, hr, pdiag, rho_push = mover.push_stacked(
                         q, e, grid_local, qm, dts,
@@ -911,89 +924,99 @@ def make_engine_step(ecfg: EngineConfig, mesh: Mesh, *, upto: str = "full",
                 #      ionized neutrals are never packed as crossers) ----
                 if ion is not None and ion[0] in idxs:
                     with tracing.phase_scope(f"engine/ionize/q{k_q}"):
-                        ni, ei, ii = ion
-                        jn = idxs.index(ni)
-                        qn = SpeciesBuffer(x=out.x[jn], v=out.v[jn],
-                                           w=out.w[jn], alive=out.alive[jn])
-                        pack = collisions.ionize_packed(
-                            ion_keys[k_q], qn, grid_local, iparams,
-                            cfg.dt if rp is None else rp.dt,
-                            ne_local, b_q)
-                        (ge, je), (gi, ji) = loc[ei], loc[ii]
-                        if use_ring:
-                            # pre-claim one electron + one ion slot per
-                            # birth under the shared min-count budget: a
-                            # birth gets both slots or neither (no half
-                            # pairs, no leaks)
-                            if ge == gi:
-                                avail = jnp.minimum(rings[ge].count[je],
-                                                    rings[ge].count[ji])
-                                rings[ge], dest, okm = _claim_rows(
-                                    rings[ge], {je: pack.ok, ji: pack.ok},
-                                    group_caps[ge], avail)
-                                allowed = okm[je]
-                                dest_e, dest_i = dest[je], dest[ji]
+                        with tracing.phase_scope("draw"):
+                            ni, ei, ii = ion
+                            jn = idxs.index(ni)
+                            qn = SpeciesBuffer(
+                                x=out.x[jn], v=out.v[jn], w=out.w[jn],
+                                alive=out.alive[jn])
+                            pack = collisions.ionize_packed(
+                                ion_keys[k_q], qn, grid_local, iparams,
+                                cfg.dt if rp is None else rp.dt,
+                                ne_local, b_q)
+                        with tracing.phase_scope("births"):
+                            (ge, je), (gi, ji) = loc[ei], loc[ii]
+                            if use_ring:
+                                # pre-claim one electron + one ion slot per
+                                # birth under the shared min-count budget: a
+                                # birth gets both slots or neither (no half
+                                # pairs, no leaks)
+                                if ge == gi:
+                                    avail = jnp.minimum(rings[ge].count[je],
+                                                        rings[ge].count[ji])
+                                    rings[ge], dest, okm = _claim_rows(
+                                        rings[ge],
+                                        {je: pack.ok, ji: pack.ok},
+                                        group_caps[ge], avail)
+                                    allowed = okm[je]
+                                    dest_e, dest_i = dest[je], dest[ji]
+                                else:
+                                    avail = jnp.minimum(rings[ge].count[je],
+                                                        rings[gi].count[ji])
+                                    rings[ge], de, oe = _claim_rows(
+                                        rings[ge], {je: pack.ok},
+                                        group_caps[ge], avail)
+                                    rings[gi], di, _ = _claim_rows(
+                                        rings[gi], {ji: pack.ok},
+                                        group_caps[gi], avail)
+                                    allowed = oe[je]
+                                    dest_e, dest_i = de[je], di[ji]
+                                # freed neutral slots feed the ring like
+                                # leavers (queue slot j -> global slot
+                                # j * n_q + k_q)
+                                rings[g] = _push_rows(
+                                    rings[g],
+                                    {jn: (pack.slot * n_q + k_q, allowed)},
+                                    b_q)
                             else:
-                                avail = jnp.minimum(rings[ge].count[je],
-                                                    rings[gi].count[ji])
-                                rings[ge], de, oe = _claim_rows(
-                                    rings[ge], {je: pack.ok},
-                                    group_caps[ge], avail)
-                                rings[gi], di, _ = _claim_rows(
-                                    rings[gi], {ji: pack.ok},
-                                    group_caps[gi], avail)
-                                allowed = oe[je]
-                                dest_e, dest_i = de[je], di[ji]
-                            # freed neutral slots feed the ring like
-                            # leavers (queue slot j -> global slot
-                            # j * n_q + k_q)
-                            rings[g] = _push_rows(
-                                rings[g],
-                                {jn: (pack.slot * n_q + k_q, allowed)}, b_q)
-                        else:
-                            allowed = pack.ok
-                            dest_e = dest_i = None
-                        killed = kill_packed(qn, pack.slot, allowed)
-                        out = StackedSpecies(
-                            x=out.x.at[jn].set(killed.x),
-                            v=out.v.at[jn].set(killed.v),
-                            w=out.w.at[jn].set(killed.w),
-                            alive=out.alive.at[jn].set(killed.alive))
-                        e_row = (pack.x, pack.v_electron, pack.w, allowed,
-                                 dest_e)
-                        i_row = (pack.x, pack.v_ion, pack.w, allowed,
-                                 dest_i)
-                        if ge == gi:
-                            birth_blocks[ge].append(_birth_block(
-                                len(groups[ge]), b_q, group_caps[ge],
-                                dtype, {je: e_row, ji: i_row}))
-                        else:
-                            birth_blocks[ge].append(_birth_block(
-                                len(groups[ge]), b_q, group_caps[ge],
-                                dtype, {je: e_row}))
-                            birth_blocks[gi].append(_birth_block(
-                                len(groups[gi]), b_q, group_caps[gi],
-                                dtype, {ji: i_row}))
-                        n_born = jnp.sum(allowed.astype(jnp.int32))
-                        dacc(None, "n_ionized", n_born)
-                        dacc(None, "birth_overflow", pack.n_events - n_born)
+                                allowed = pack.ok
+                                dest_e = dest_i = None
+                            killed = kill_packed(qn, pack.slot, allowed)
+                            out = StackedSpecies(
+                                x=out.x.at[jn].set(killed.x),
+                                v=out.v.at[jn].set(killed.v),
+                                w=out.w.at[jn].set(killed.w),
+                                alive=out.alive.at[jn].set(killed.alive))
+                            e_row = (pack.x, pack.v_electron, pack.w, allowed,
+                                     dest_e)
+                            i_row = (pack.x, pack.v_ion, pack.w, allowed,
+                                     dest_i)
+                            if ge == gi:
+                                birth_blocks[ge].append(_birth_block(
+                                    len(groups[ge]), b_q, group_caps[ge],
+                                    dtype, {je: e_row, ji: i_row}))
+                            else:
+                                birth_blocks[ge].append(_birth_block(
+                                    len(groups[ge]), b_q, group_caps[ge],
+                                    dtype, {je: e_row}))
+                                birth_blocks[gi].append(_birth_block(
+                                    len(groups[gi]), b_q, group_caps[gi],
+                                    dtype, {ji: i_row}))
+                            n_born = jnp.sum(allowed.astype(jnp.int32))
+                            dacc(None, "n_ionized", n_born)
+                            dacc(None, "birth_overflow",
+                                 pack.n_events - n_born)
 
                 with tracing.phase_scope(f"engine/migrate/q{k_q}"):
-                    (kept, pack_l, pack_r, lv_x, lv_w, free_idx, free_ok,
-                     abs_l, abs_r, dmig) = _exchange_queue(
-                        out, l_local, m_q, cfg.boundary, is_first, is_last)
+                    with tracing.phase_scope("pack"):
+                        (kept, pack_l, pack_r, lv_x, lv_w, free_idx,
+                         free_ok, abs_l, abs_r, dmig) = _exchange_queue(
+                            out, l_local, m_q, cfg.boundary, is_first,
+                            is_last)
                     if carried:
                         # leavers were deposited at their raw (edge-clipped)
                         # positions by the in-pass deposit; take them back
                         # out
-                        rho_acc = rho_push - deposit_flat(
-                            grid_local, lv_x, charges[:, None] * lv_w)
+                        with tracing.phase_scope("deposit"):
+                            rho_acc = rho_push - deposit_flat(
+                                grid_local, lv_x, charges[:, None] * lv_w)
                     if use_ring:
                         # leaver slots are free from here on: feed the ring
                         # from the already-packed indices (queue slot j ->
                         # global slot j * n_q + k_q), no extra scan
-                        rings[g] = jax.vmap(ring_push)(
-                            rings[g], free_idx * n_q + k_q, free_ok)
+                        with tracing.phase_scope("ring"):
+                            rings[g] = jax.vmap(ring_push)(
+                                rings[g], free_idx * n_q + k_q, free_ok)
 
                     # ---- SEE: yield-thinned secondaries off this queue's
                     #      absorbed rows (already packed by the exchange) --
@@ -1021,10 +1044,11 @@ def make_engine_step(ecfg: EngineConfig, mesh: Mesh, *, upto: str = "full",
                             dacc(cfg.species[t].name, "emission_overflow",
                                  jnp.sum((emit & ~ok_t).astype(jnp.int32)))
 
-                    recv_r = halo.ppermute_tree(pack_l, axis_names, -1,
-                                                mesh)
-                    recv_l = halo.ppermute_tree(pack_r, axis_names, +1,
-                                                mesh)
+                    with tracing.phase_scope("send"):
+                        recv_r = halo.ppermute_tree(pack_l, axis_names, -1,
+                                                    mesh)
+                        recv_l = halo.ppermute_tree(pack_r, axis_names, +1,
+                                                    mesh)
                     kept_qs.append(StackedSpecies(
                         x=kept.x, v=kept.v, w=kept.w, alive=kept.alive))
                     pending_packs.append((recv_l, recv_r))
@@ -1058,43 +1082,51 @@ def make_engine_step(ecfg: EngineConfig, mesh: Mesh, *, upto: str = "full",
                     pending_packs) in enumerate(staged):
                 scs = [cfg.species[i] for i in idxs]
                 cap_g = group_caps[g]
-                full = _merge_queues(kept_qs, n_q)
-                packs = [p for pair in pending_packs for p in pair]
-                cand = jax.tree.map(
-                    lambda *xs: jnp.concatenate(xs, axis=1), *packs)
-                if use_ring:
-                    rings[g], dest, accepted = jax.vmap(
-                        lambda rg, wnt: ring_claim(rg, wnt, cap_g))(
-                        rings[g], cand.alive)
-                    blocks = [PendingArrivals(
-                        x=cand.x, v=cand.v, w=cand.w * accepted,
-                        alive=cand.alive & accepted, dest=dest)]
-                    blocks += birth_blocks[g]
-                    pend_g = blocks[0] if len(blocks) == 1 else jax.tree.map(
-                        lambda *xs: jnp.concatenate(xs, axis=1), *blocks)
-                    pend_out[g] = pend_g
-                    dropped = jnp.sum(
-                        (cand.alive & ~accepted).astype(jnp.int32), axis=1)
-                    write_back(idxs, full)
-                    if carried:
-                        rho_acc = rho_acc + deposit_flat(
-                            grid_local, pend_g.x,
-                            charges[:, None] * pend_g.w * pend_g.alive)
-                else:
-                    extra = [SpeciesBuffer(x=b.x, v=b.v, w=b.w,
-                                           alive=b.alive)
-                             for b in birth_blocks[g]]
-                    cand_all = cand if not extra else jax.tree.map(
-                        lambda *xs: jnp.concatenate(xs, axis=1), cand,
-                        *extra)
-                    merged, dropped, accepted = _inject_rows(full, cand_all)
-                    write_back(idxs, merged)
-                    if carried:
-                        rho_acc = rho_acc + deposit_flat(
-                            grid_local, cand_all.x,
-                            charges[:, None] * cand_all.w * accepted)
-                for j, sc in enumerate(scs):
-                    dacc(sc.name, "merge_dropped", dropped[j])
+                # the kept queues back in slot order: one gather
+                with tracing.phase_scope("layout"):
+                    full = _merge_queues(kept_qs, n_q)
+                    if use_ring:
+                        write_back(idxs, full)
+                with tracing.phase_scope("claim"):
+                    packs = [p for pair in pending_packs for p in pair]
+                    cand = jax.tree.map(
+                        lambda *xs: jnp.concatenate(xs, axis=1), *packs)
+                    if use_ring:
+                        rings[g], dest, accepted = jax.vmap(
+                            lambda rg, wnt: ring_claim(rg, wnt, cap_g))(
+                            rings[g], cand.alive)
+                        blocks = [PendingArrivals(
+                            x=cand.x, v=cand.v, w=cand.w * accepted,
+                            alive=cand.alive & accepted, dest=dest)]
+                        blocks += birth_blocks[g]
+                        pend_g = (blocks[0] if len(blocks) == 1
+                                  else jax.tree.map(
+                                      lambda *xs: jnp.concatenate(xs, axis=1),
+                                      *blocks))
+                        pend_out[g] = pend_g
+                        dropped = jnp.sum(
+                            (cand.alive & ~accepted).astype(jnp.int32),
+                            axis=1)
+                        if carried:
+                            rho_acc = rho_acc + deposit_flat(
+                                grid_local, pend_g.x,
+                                charges[:, None] * pend_g.w * pend_g.alive)
+                    else:
+                        extra = [SpeciesBuffer(x=b.x, v=b.v, w=b.w,
+                                               alive=b.alive)
+                                 for b in birth_blocks[g]]
+                        cand_all = cand if not extra else jax.tree.map(
+                            lambda *xs: jnp.concatenate(xs, axis=1), cand,
+                            *extra)
+                        merged, dropped, accepted = _inject_rows(full,
+                                                                 cand_all)
+                        write_back(idxs, merged)
+                        if carried:
+                            rho_acc = rho_acc + deposit_flat(
+                                grid_local, cand_all.x,
+                                charges[:, None] * cand_all.w * accepted)
+                    for j, sc in enumerate(scs):
+                        dacc(sc.name, "merge_dropped", dropped[j])
         rho_out = rho_acc[None] if carried else state.rho
         if upto == "merge":
             return pack_state(rho_out, pend_out), e[None]

@@ -5,7 +5,7 @@
         [--rebalance-skew T] [--cell-order] [--max-births N] \
         [--see-yield Y] [--collisions elastic,cx,coulomb] \
         [--strategy unified|explicit|async_batched|fused] \
-        [--field-solve] [--diag-every K] [--phases] \
+        [--field-solve] [--diag-every K] [--profile-dir DIR] \
         [--ckpt-dir DIR --ckpt-every K] [--resume] [--fail-at-step N]
 
 --domains > 1 runs the asynchronous multi-device engine
@@ -26,12 +26,15 @@ cell so the queue slices stay cell-striped. If the process exposes
 fewer jax devices than --domains, emulated host devices are requested via
 XLA_FLAGS before jax initializes (a TPU slice provides real ones
 natively); the first line printed names the platform, device kind and
-device count the run used. --phases prints the per-phase timing
-breakdown.
+device count the run used.
 
 Observability (``repro.obs``): --profile-dir DIR captures a profiler trace
-of the run (``jax.profiler.start_trace``; open in TensorBoard/Perfetto —
-the engine's named phase scopes appear as ranges); --metrics-jsonl FILE
+of the run (``jax.profiler.start_trace``; open in TensorBoard/Perfetto):
+every device op of the engine step carries its phase scope
+(``engine/<phase>[/q<k>][/<part>]``, the scope table of
+``docs/observability.md``) and each step is a ``pic_run/step`` range on
+the host track, with ``pic_run/metrics`` around the per-step metrics
+record (the capture opens after the compile); --metrics-jsonl FILE
 streams one structured metrics record per engine step (schema in
 ``docs/observability.md``); --autotune lets the online controller retune
 the engine knobs (async_n, migration/birth budgets, rebalance triggers)
@@ -96,11 +99,12 @@ def main() -> None:
     ap.add_argument("--diag-every", type=int, default=1,
                     help="compute full diagnostics every K-th step "
                          "(single-domain only)")
-    ap.add_argument("--phases", action="store_true",
-                    help="print the per-phase timing breakdown (multi-domain)")
     ap.add_argument("--profile-dir", default="",
                     help="capture a jax profiler trace of the run into this "
-                         "directory (TensorBoard/Perfetto)")
+                         "directory (TensorBoard/Perfetto); its device ops "
+                         "carry the engine's phase scopes, which give the "
+                         "step's per-phase times (scope table: "
+                         "docs/observability.md)")
     ap.add_argument("--metrics-jsonl", default="",
                     help="stream per-step engine metrics records to this "
                          "JSONL file (engine path; schema in "
@@ -161,7 +165,7 @@ def main() -> None:
                                         make_collision_menu,
                                         make_engine_config, make_see_config)
     from repro.core import pic
-    from repro.distributed import engine, perf
+    from repro.distributed import engine
     from repro.launch.mesh import make_debug_mesh
 
     if args.see_yield > 0.0:
@@ -285,8 +289,10 @@ def main() -> None:
             from repro.obs.autotune import AutoTuner
             tuner = AutoTuner(ecfg, mesh, stream=stream)
             with tracing.trace_session(profile_dir):
-                for _ in range(args.steps):
-                    state, diag = tuner.run_step(state)
+                for i in range(args.steps):
+                    with jax.profiler.StepTraceAnnotation("pic_run/step",
+                                                          step_num=i):
+                        state, diag = tuner.run_step(state)
             ecfg = tuner.ecfg
             for line in tuner.log:
                 print("autotune:", line)
@@ -295,13 +301,16 @@ def main() -> None:
             if profile_dir:
                 step = step.lower(state).compile()  # compile outside trace
             with tracing.trace_session(profile_dir):
-                for _ in range(args.steps):
-                    ts = time.perf_counter()
-                    state, diag = step(state)
-                    if stream is not None:
-                        jax.block_until_ready(diag)
-                        stream.record(
-                            diag, wall_us=(time.perf_counter() - ts) * 1e6)
+                for i in range(args.steps):
+                    with jax.profiler.StepTraceAnnotation("pic_run/step",
+                                                          step_num=i):
+                        ts = time.perf_counter()
+                        state, diag = step(state)
+                        if stream is not None:
+                            with tracing.host_span("pic_run/metrics"):
+                                jax.block_until_ready(diag)
+                                stream.record(diag, wall_us=(
+                                    time.perf_counter() - ts) * 1e6)
                 jax.block_until_ready(state.species[0].x)
         if stream is not None:
             print("metrics:", stream.summary())
@@ -326,19 +335,6 @@ def main() -> None:
     print("final populations:", counts)
     if balance:
         print("queue balance:", balance)
-
-    if args.phases:
-        if mesh is None:
-            print("--phases times the engine pipeline; pass --domains or "
-                  "--async-n > 1 (the single-domain run above used the "
-                  "plain hot loop)")
-        else:
-            probe = perf.phase_breakdown(ecfg, mesh, iters=3, warmup=1)
-            print("per-phase (us/step):",
-                  {k: round(v, 1) for k, v in probe["phases"].items()},
-                  f"total={probe['total']:.1f}")
-            for flag in probe["flags"]:
-                print("probe flag:", flag)
 
 
 if __name__ == "__main__":

@@ -161,14 +161,21 @@ def test_engine_phase_scopes_reach_the_jaxpr():
     step = engine.make_engine_step(ecfg, mesh, donate=False)
     with tracing.capture_scopes() as seen:
         closed = jax.make_jaxpr(step)(state)
-    for want in ("engine/ingest", "engine/field", "engine/push/q0",
+    for want in ("engine/state", "engine/ingest", "engine/field",
+                 "engine/sources", "engine/split", "engine/push/q0",
                  "engine/push/q1", "engine/ionize/q0", "engine/migrate/q1",
                  "engine/merge", "engine/diag"):
         assert want in seen, (want, sorted(set(seen)))
     stacks = tracing.jaxpr_scope_names(closed)
     for want in ("engine/push/q0", "engine/push/q1", "engine/migrate/q0",
                  "engine/merge", "engine/diag", "halo/sum", "halo/poisson",
-                 "halo/efield", "halo/ppermute"):
+                 "halo/efield", "halo/ppermute", "engine/split",
+                 "engine/sources/ne_deposit", "engine/push/q0/field_gather",
+                 "engine/push/q1/move", "engine/push/q0/deposit",
+                 "engine/ionize/q0/draw", "engine/ionize/q1/births",
+                 "engine/migrate/q1/pack", "engine/migrate/q0/ring",
+                 "engine/migrate/q0/send/halo/ppermute",
+                 "engine/merge/layout", "engine/merge/claim"):
         assert any(want in s for s in stacks), (want, len(stacks))
 
 

@@ -11,7 +11,13 @@ Widths: nc = 4,096 (the bench configs) and the widest each kernel takes —
 the paper's 102,400 cells (``pic_bit1.NC_GLOBAL``) for the gather-free
 mover and the fused cycle without deposit, ``MAX_NG_PAD`` for the one-hot
 deposits, whose next tile the compiler refuses for VMEM.
+
+The engine step is compiled too, at a small size, to pin its phase-scope
+coverage on the program the chip runs: every instruction whose op_name the
+program wrote lies under an ``engine/`` or ``halo/`` scope.
 """
+
+import re
 
 import pytest
 
@@ -143,3 +149,76 @@ def test_too_wide_raises_named_error_on_tpu(monkeypatch, kernel):
                 x, jnp.zeros((1024, 3)), x > 0, x, jnp.zeros((ng,)),
                 x0=0.0, dx=1.0, length=float(ng - 1), qm=-1.0, dt=0.1,
                 charge=-1.0)
+
+
+# ------------------------------------------------- engine step scope coverage
+
+_COMP = re.compile(r"^(ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$")
+_INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_CALLED = re.compile(r"(?:body|condition|true_computation|false_computation"
+                     r")=%?([\w.\-]+)")
+_BRANCHES = re.compile(r"branch_computations=\{([^}]*)\}")
+_SCOPED = re.compile(r"(?:^|/)(?:engine|halo)/")
+
+
+def _top_level_op_names(hlo: str) -> dict:
+    """``{instruction: op_name}`` of the instructions the device runs as
+    ops: those of the entry computation and of the loop and branch bodies
+    it calls, not those inside fusions (a fusion takes its root's
+    op_name) or reducers."""
+    comps, cur, entry = {}, None, None
+    for line in hlo.splitlines():
+        m = _COMP.match(line)
+        if m and not line.startswith(" "):
+            cur = m.group(2)
+            comps[cur] = []
+            entry = cur if m.group(1) else entry
+        elif line.strip() == "}":
+            cur = None
+        elif cur:
+            comps[cur].append(line)
+    out, todo, seen = {}, [entry], set()
+    while todo:
+        c = todo.pop()
+        if c in seen or c not in comps:
+            continue
+        seen.add(c)
+        for line in comps[c]:
+            todo += _CALLED.findall(line)
+            for grp in _BRANCHES.findall(line):
+                todo += [n.strip().lstrip("%") for n in grp.split(",")]
+            m = _INSTR.match(line)
+            if m:
+                op = _OP_NAME.search(line)
+                out[m.group(1)] = op.group(1) if op else ""
+    return out
+
+
+def test_engine_step_ops_are_all_scoped(topo):
+    """The ionization step for one v5e domain (nc 4,096, 2**14 slots per
+    species, async_n 2): no instruction with a JAX op_name (``jit(...)``)
+    lies outside ``engine/`` or ``halo/``; the ops the compiler makes on
+    its own carry no op_name and are not counted. The finer scopes the
+    benchmark reads reach the compiled program."""
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from repro.configs.pic_bit1 import make_bench_config, make_engine_config
+    from repro.distributed import engine
+
+    mesh = Mesh(np.asarray(topo.devices[:1]).reshape(1, 1), ("data", "model"))
+    ecfg = make_engine_config(make_bench_config(nc=4096, n=8192), async_n=2,
+                              max_migration=512, max_births=1024)
+    args = jax.tree.map(
+        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+        engine.state_shape(ecfg, mesh), engine.state_shardings(ecfg, mesh))
+    hlo = engine.make_engine_step(ecfg, mesh).lower(args).compile().as_text()
+    ops = _top_level_op_names(hlo)
+    outside = {i: op for i, op in ops.items()
+               if op.startswith("jit(") and not _SCOPED.search(op)}
+    assert not outside, outside
+    for scope in ("engine/split/", "engine/push/q0/field_gather/",
+                  "engine/push/q1/move/", "engine/merge/layout/",
+                  "engine/migrate/q0/pack/", "engine/ionize/q1/draw/"):
+        assert any(scope in op for op in ops.values()), scope
