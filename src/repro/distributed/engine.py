@@ -122,6 +122,7 @@ from repro.core.params import RuntimeParams, b_active
 from repro.core.pic import PICConfig, PICState
 from repro.core.pic import _carries_rho as pic_carries_rho
 from repro.distributed import halo
+from repro.kernels import ops
 from repro.obs import tracing
 
 Array = jax.Array
@@ -353,9 +354,11 @@ def _group_pending_rows(ecfg: EngineConfig, groups) -> list[int]:
     return rows
 
 
-# The queue views below never form a (cap / n, n) array: on TPU the minor
+# The queue layout never forms a (cap / n, n) array: on TPU the minor
 # dimension of an array is tiled to 128 lanes, so n = 2 there would pad the
 # whole particle buffer 64-fold (tens of GB at 16 Mi slots per species).
+# Nor does it index: JAX lowers ``a[:, k::n]`` and a ``take`` back to slot
+# order as XLA gathers, which move the buffer element by element.
 
 
 def _split_queues(st: StackedSpecies, n: int) -> list[StackedSpecies]:
@@ -363,27 +366,24 @@ def _split_queues(st: StackedSpecies, n: int) -> list[StackedSpecies]:
     live block evenly spread across queues), as strided slices."""
     if n == 1:
         return [st]
-    return [jax.tree.map(lambda a: a[:, k::n], st) for k in range(n)]
+    return [jax.tree.map(lambda a: jax.lax.slice_in_dim(
+        a, k, a.shape[1], stride=n, axis=1), st) for k in range(n)]
 
 
 def _merge_queues(queues: list, n: int):
     """Inverse of ``_split_queues`` (works on any matching pytrees): the
-    queues laid end to end, then one gather back into slot order."""
+    queues interleaved back into slot order by a lane-shuffle kernel
+    (``kernels/interleave.py``), bit for bit."""
     if n == 1:
         return queues[0]
-
-    def mg(*xs):
-        capq = xs[0].shape[1]
-        c = jnp.arange(capq * n)
-        return jnp.take(jnp.concatenate(xs, axis=1),
-                        (c % n) * capq + c // n, axis=1)
-
-    return jax.tree.map(mg, *queues)
+    return jax.tree.map(lambda *xs: ops.interleave(xs, axis=1), *queues)
 
 
 def _queue_occupancy(alive: Array, n: int) -> Array:
-    """(cap,) alive mask -> (n,) per-queue alive counts (slot c -> c % n)."""
-    return jnp.stack([jnp.sum(alive[k::n].astype(jnp.int32))
+    """(cap,) alive mask -> (n,) per-queue alive counts (slot c -> c % n),
+    each a masked sum over the whole mask."""
+    queue = jax.lax.iota(jnp.int32, alive.shape[0]) % n
+    return jnp.stack([jnp.sum((alive & (queue == k)).astype(jnp.int32))
                       for k in range(n)])
 
 
@@ -845,7 +845,7 @@ def make_engine_step(ecfg: EngineConfig, mesh: Mesh, *, upto: str = "full",
         birth_blocks: list[list] = [[] for _ in groups]
         for g, idxs in enumerate(groups):
             # ---- the group stacked and split into its interleaved queues
-            #      (strided views, which the TPU lowers to row gathers) ----
+            #      (strided slices) ----
             with tracing.phase_scope("engine/split"):
                 scs, qm, dts, charges = group_meta(idxs)
                 strides = [sc.stride for sc in scs]
@@ -1082,7 +1082,7 @@ def make_engine_step(ecfg: EngineConfig, mesh: Mesh, *, upto: str = "full",
                     pending_packs) in enumerate(staged):
                 scs = [cfg.species[i] for i in idxs]
                 cap_g = group_caps[g]
-                # the kept queues back in slot order: one gather
+                # the kept queues back in slot order
                 with tracing.phase_scope("layout"):
                     full = _merge_queues(kept_qs, n_q)
                     if use_ring:

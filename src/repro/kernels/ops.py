@@ -26,6 +26,7 @@ from repro.core.particles import LANES, from_planes, to_planes
 from repro.kernels import collide as _collide
 from repro.kernels import deposit as _deposit
 from repro.kernels import fused_cycle as _fused
+from repro.kernels import interleave as _interleave
 from repro.kernels import mover as _mover
 
 Array = jax.Array
@@ -133,6 +134,39 @@ def fused_push_deposit(x: Array, v: Array, alive: Array, w: Array, e: Array,
         rho_out = rho_carry + rho_out
     return (unpad(xn), v_out, unpad(an) > 0.5, unpad(hl) > 0.5,
             unpad(hr) > 0.5, unpad(wn), rho_out)
+
+
+# lanes of each input per grid step of the interleave kernel
+INTERLEAVE_BLOCK = 4096
+
+
+def interleave(xs: tuple[Array, ...], axis: int) -> Array:
+    """n arrays of one shape (2-D or more), interleaved along ``axis``:
+    ``out[..., n*i + k, ...] = xs[k][..., i, ...]`` (kernels/interleave.py),
+    bit for bit, for any n and length and for 32-bit and bool dtypes. The
+    axis is moved last (a bitcast where it is the minor axis of the TPU
+    layout, as for the engine's (S, cap, 3) velocities) and padded to whole
+    kernel blocks when its length needs it."""
+    n = len(xs)
+    if n == 1:
+        return xs[0]
+    dtype = xs[0].dtype
+    m = xs[0].shape[axis]
+    block = min(INTERLEAVE_BLOCK, m + (-m) % LANES)
+    pad = (-m) % block
+    rows = []
+    for x in xs:
+        x = jnp.moveaxis(x, axis, -1)
+        if dtype == jnp.bool_:
+            x = x.astype(jnp.uint8)
+        if pad:
+            x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
+        rows.append(x)
+    out = _interleave.interleave_pallas(*rows, block=block,
+                                        interpret=_interpret())[..., :n * m]
+    if dtype == jnp.bool_:
+        out = out != 0
+    return jnp.moveaxis(out, -1, axis)
 
 
 @partial(jax.jit, static_argnames=("tile_rows",))

@@ -195,12 +195,15 @@ def _top_level_op_names(hlo: str) -> dict:
     return out
 
 
-def test_engine_step_ops_are_all_scoped(topo):
+# the slots of each species in the compiled step below, and its queues
+ENGINE_CAP, ENGINE_ASYNC_N = 2 ** 14, 2
+
+
+@pytest.fixture(scope="module")
+def engine_hlo(topo):
     """The ionization step for one v5e domain (nc 4,096, 2**14 slots per
-    species, async_n 2): no instruction with a JAX op_name (``jit(...)``)
-    lies outside ``engine/`` or ``halo/``; the ops the compiler makes on
-    its own carry no op_name and are not counted. The finer scopes the
-    benchmark reads reach the compiled program."""
+    species, async_n 2), compiled as the chip runs it: the Pallas kernels
+    compiled, not interpreted (``ops`` sees the CPU backend here)."""
     import numpy as np
     from jax.sharding import Mesh
 
@@ -208,17 +211,61 @@ def test_engine_step_ops_are_all_scoped(topo):
     from repro.distributed import engine
 
     mesh = Mesh(np.asarray(topo.devices[:1]).reshape(1, 1), ("data", "model"))
-    ecfg = make_engine_config(make_bench_config(nc=4096, n=8192), async_n=2,
-                              max_migration=512, max_births=1024)
+    ecfg = make_engine_config(
+        make_bench_config(nc=4096, n=ENGINE_CAP // 2),
+        async_n=ENGINE_ASYNC_N, max_migration=512, max_births=1024)
     args = jax.tree.map(
         lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
         engine.state_shape(ecfg, mesh), engine.state_shardings(ecfg, mesh))
-    hlo = engine.make_engine_step(ecfg, mesh).lower(args).compile().as_text()
-    ops = _top_level_op_names(hlo)
-    outside = {i: op for i, op in ops.items()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ops, "_interpret", lambda: False)
+        return engine.make_engine_step(ecfg, mesh).lower(args).compile(
+            ).as_text()
+
+
+def test_engine_step_ops_are_all_scoped(engine_hlo):
+    """No instruction of the engine step with a JAX op_name (``jit(...)``)
+    lies outside ``engine/`` or ``halo/``; the ops the compiler makes on
+    its own carry no op_name and are not counted. The finer scopes the
+    benchmark reads reach the compiled program."""
+    ops_ = _top_level_op_names(engine_hlo)
+    outside = {i: op for i, op in ops_.items()
                if op.startswith("jit(") and not _SCOPED.search(op)}
     assert not outside, outside
     for scope in ("engine/split/", "engine/push/q0/field_gather/",
                   "engine/push/q1/move/", "engine/merge/layout/",
                   "engine/migrate/q0/pack/", "engine/ionize/q1/draw/"):
-        assert any(scope in op for op in ops.values()), scope
+        assert any(scope in op for op in ops_.values()), scope
+
+
+_DEF = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*([a-z]\w*)"
+                  r"\[([\d,]*)\]")
+_INDEXED = re.compile(r"=\s*\S+\s+(gather|scatter)\(%?[\w.\-]+,\s*%?"
+                      r"([\w.\-]+)")
+
+
+def test_queue_layout_does_not_index(engine_hlo):
+    """The queue split, the merge back to slot order and the diagnostics'
+    per-queue counts compile to no gather (JAX lowers ``a[:, k::n]`` and a
+    ``take`` to gathers, which move the buffer element by element), and
+    the split and merge to no scatter either. The diagnostics' scatters
+    are the pending rows landed for the counts: their indices cover the
+    pending rows, far fewer than a queue's slots."""
+    dims = {m.group(1): m.group(3) for m in map(_DEF.match,
+                                                engine_hlo.splitlines()) if m}
+    found = []
+    for line in engine_hlo.splitlines():
+        m = _INDEXED.search(line)
+        op = _OP_NAME.search(line)
+        if m and op:
+            found.append((m.group(1), op.group(1),
+                          int(dims[m.group(2)].split(",")[0])))
+    assert any(kind == "gather" for kind, _, _ in found)   # the parse works
+    assert any("tpu_custom_call" in line and "engine/merge/layout/" in line
+               for line in engine_hlo.splitlines())
+    for kind, op, rows in found:
+        assert "engine/split/" not in op, op
+        assert "engine/merge/layout/" not in op, op
+        if "engine/diag/" in op:
+            assert kind == "scatter", op
+            assert rows < ENGINE_CAP // ENGINE_ASYNC_N, (op, rows)
