@@ -360,6 +360,21 @@ def kill_packed(buf: SpeciesBuffer, idx: Array, ok: Array) -> SpeciesBuffer:
     return kill(buf, gone)
 
 
+def first_true_indices(mask: Array, size: int) -> Array:
+    """Indices of the first ``size`` True entries of the 1-D ``mask``, in
+    order, padded with ``len(mask)``: bit for bit
+    ``jnp.nonzero(mask, size=size, fill_value=len(mask))[0]``.
+
+    ``jnp.nonzero`` with a size scatter-adds one update per entry of the
+    mask (a ``bincount`` of its running count), whatever the mask holds.
+    Here the k-th True is the first index where the running count reaches
+    k, found by a binary search over it: ``size`` x log2(len) gathered
+    elements and no scatter, for a few crossers in millions of slots."""
+    count = jnp.cumsum(mask.astype(jnp.int32))
+    return jnp.searchsorted(count, jnp.arange(1, size + 1, dtype=jnp.int32),
+                            side="left", method="scan")
+
+
 def take(buf: SpeciesBuffer, idx: Array) -> SpeciesBuffer:
     """Gather a sub-buffer (used to build migration send buffers)."""
     cap = buf.capacity

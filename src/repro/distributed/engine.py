@@ -114,8 +114,9 @@ from repro.core import boundaries, collisions, diagnostics, mover
 from repro.core.grid import (Grid1D, deposit_density, deposit_stacked,
                              deposit_flat)
 from repro.core.particles import (FreeSlotRing, SpeciesBuffer, StackedSpecies,
-                                  init_uniform, inject_at, inject_masked,
-                                  kill, kill_packed, ring_claim,
+                                  first_true_indices, init_uniform,
+                                  inject_at, inject_masked, kill,
+                                  kill_packed, ring_claim,
                                   ring_from_counts, ring_init, ring_push,
                                   sort_by_cell, stack_species, take)
 from repro.core.params import RuntimeParams, b_active
@@ -410,9 +411,10 @@ def _exchange_queue(q, l_local: float, m: int, boundary: str,
         go_l = alive & (x < 0.0)
         go_r = alive & (x >= l_local)
         leave = go_l | go_r
-        # ONE full-capacity packing scan for both directions (a particle
-        # crosses at most one boundary); per-direction work is on 2m only
-        idx = jnp.nonzero(leave, size=2 * m, fill_value=cap)[0]
+        # ONE packing search over the whole queue for both directions (a
+        # particle crosses at most one boundary); per-direction work is on
+        # 2m only. Slot ``cap`` pads the rows past the crossers.
+        idx = first_true_indices(leave, 2 * m)
         packed = take(buf, idx)
         went_l = packed.alive & (packed.x < 0.0)
         went_r = packed.alive & (packed.x >= l_local)

@@ -19,6 +19,7 @@ runs: every instruction whose op_name the program wrote lies under an
 under one of the exchange scopes.
 """
 
+import math
 import re
 
 import pytest
@@ -306,3 +307,47 @@ def test_queue_layout_does_not_index(engine_hlo):
         if "engine/diag/" in op:
             assert kind == "scatter", op
             assert rows < ENGINE_CAP // ENGINE_ASYNC_N, (op, rows)
+
+
+_SCATTER = re.compile(r"=\s*\S+\s+scatter\(%?[\w.\-]+,\s*%?([\w.\-]+),"
+                      r".*\bindex_vector_dim=(\d+)")
+# the scatters of the step whose indices may cover a queue's slots
+_PER_SLOT_SCATTER = re.compile(r"engine/sources/ne_deposit/|"
+                               r"engine/ionize/q\d+/draw/|/deposit/")
+
+
+def test_no_scatter_takes_an_index_per_queue_slot(engine_hlo):
+    """No scatter of the engine step, scoped or not, takes as many indices
+    as a queue has slots, except those the step has a reason for:
+
+    - the n_e deposit (``engine/sources/ne_deposit``): every electron adds
+      its weight to its two nodes;
+    - the MC draw's own compaction (``engine/ionize/q<k>/draw``):
+      ``jnp.nonzero``'s ``bincount`` over the queue's running count;
+    - any charge deposit (``.../deposit/``), one index per row deposited.
+
+    The migration pack finds its crossers by a binary search over the
+    leave mask's running count (``particles.first_true_indices``); a
+    ``jnp.nonzero`` put back there scatter-adds one update per slot of
+    every queue, with no op_name, and fails here."""
+    _, hlo = engine_hlo
+    dims = {m.group(1): m.group(3) for m in map(_DEF.match,
+                                                hlo.splitlines()) if m}
+    per_slot = []
+    for line in hlo.splitlines():
+        m = _SCATTER.search(line)
+        if not m:
+            continue
+        shape = [int(s) for s in dims[m.group(1)].split(",") if s]
+        vec = int(m.group(2))
+        n_idx = math.prod(shape) // (shape[vec] if vec < len(shape)
+                                        else 1)
+        if n_idx >= ENGINE_CAP // ENGINE_ASYNC_N:
+            op = _OP_NAME.search(line)
+            per_slot.append((_INSTR.match(line).group(1),
+                             op.group(1) if op else "", n_idx))
+    kept = [op for _, op, _ in per_slot if _PER_SLOT_SCATTER.search(op)]
+    assert any("ne_deposit/" in op for op in kept), per_slot  # parse works
+    assert any("/draw/" in op for op in kept), per_slot
+    loose = [s for s in per_slot if not _PER_SLOT_SCATTER.search(s[1])]
+    assert not loose, loose
