@@ -1,13 +1,17 @@
 """The asynchronous multi-device engine end-to-end on emulated devices.
 
 Runs the BIT1 scenario under the async(n) queue scheduler with the
-halo-exchange field phase, verifies conservation against the initial
-population, and prints the per-phase timing breakdown the paper reports
-from Nsight (here: wall-clock probe differencing, see
-``repro/distributed/perf.py``).
+halo-exchange field phase and verifies conservation against the initial
+population.
 
     PYTHONPATH=src python examples/pic_async_multidevice.py \
         --domains 4 --async-n 2 [--steps 40]
+
+For the per-phase timeline the paper reads from Nsight, capture a trace of
+the same run with ``python -m repro.launch.pic_run --domains 4 --async-n 2
+--profile-dir <dir>``: every phase of the step is a named scope there
+(``engine/push/q<k>``, ``engine/migrate/q<k>``, ``halo/...``; the tree is
+in ``docs/observability.md``).
 
 Emulated host devices are requested automatically when the process exposes
 fewer devices than --domains (a TPU slice provides real ones natively).
@@ -47,7 +51,7 @@ def main() -> None:
     import numpy as np
 
     from repro.configs.pic_bit1 import make_bench_config, make_engine_config
-    from repro.distributed import engine, perf
+    from repro.distributed import engine
     from repro.launch.mesh import make_debug_mesh
 
     mesh = make_debug_mesh(data=args.domains, model=1)
@@ -96,15 +100,6 @@ def main() -> None:
         ok &= cnt == want
     assert ok, "conservation FAILED"
     print("conservation PASSED")
-
-    probe = perf.phase_breakdown(ecfg, mesh, iters=3, warmup=1)
-    phases = dict(probe["phases"], total=probe["total"])
-    width = max(len(k) for k in phases)
-    print("per-phase breakdown (us/step):")
-    for k, v in phases.items():
-        print(f"  {k:<{width}} {v:10.1f}")
-    for flag in probe["flags"]:
-        print("  probe flag:", flag)
 
 
 if __name__ == "__main__":

@@ -1,6 +1,7 @@
 #!/usr/bin/env bash
-# CI entry point: tier-1 tests + multi-device lane + smoke perf benchmarks
-# + perf-regression gate + docs lane.
+# CI entry point: tier-1 tests + multi-device lane + docs, resilience and
+# serving lanes. Performance is measured on the chip, by the benchmark in
+# BENCHMARK.json and chipbench/, not here.
 #
 # Lane 1: the full tier-1 suite on the default single device (multi-device
 #         tests spawn their own emulated-device subprocesses).
@@ -8,28 +9,18 @@
 #         (ionization/SEE) and binary-collision tests again with 4 emulated
 #         host devices IN-process (XLA_FLAGS) — exercises shard_map
 #         collectives without the subprocess indirection.
-# Lane 3: the smoke benchmarks: mover strategies (BENCH_smoke.json) and the
-#         engine scaling sweep with per-phase times + speedup/PE. The
-#         scaling sweep writes to BENCH_scaling.fresh.json — NOT the
-#         committed BENCH_scaling.json, which is the baseline the perf gate
-#         diffs against. Full-size results that gate perf PRs live in
-#         BENCH_mover.json / BENCH_scaling.json (python -m benchmarks.run).
-# Lane 4: perf gate — scripts/check_perf.py validates the committed
-#         BENCH_scaling.json structure (every phase <= total, probes carry
-#         noise bounds) and fails on order-of-magnitude regressions of the
-#         fresh smoke totals vs the committed trajectory.
-# Lane 5: docs — no broken relative links in README.md / docs/, and the
+# Lane 3: docs — no broken relative links in README.md / docs/, and the
 #         README quickstart commands actually run (keep these in sync with
 #         the "Quickstart" section of README.md), including an
 #         observability smoke: --profile-dir trace capture + a metrics
 #         JSONL stream validated against the schema.
-# Lane 6: resilience — a 4-device in-process save -> kill -> resume ->
+# Lane 4: resilience — a 4-device in-process save -> kill -> resume ->
 #         bitwise-compare smoke of the checkpoint/restore layer, plus the
 #         CLI drill: --fail-at-step, --resume at the same D, then an
 #         elastic --resume at a different D. The full matrix (D x async_n,
 #         torn writes, elastic conservation) runs in lane 1 via
 #         tests/test_resilience.py.
-# Lane 7: serving — the simulation-as-a-service smoke: three sessions at
+# Lane 5: serving — the simulation-as-a-service smoke: three sessions at
 #         DISTINCT parameter points through a width-2 ensemble server
 #         (submit -> step -> poll), asserting distinct final diagnostics,
 #         slot reuse, and exactly ONE compile of the vmapped step; plus
@@ -45,12 +36,6 @@ python -m pytest -x -q
 XLA_FLAGS="--xla_force_host_platform_device_count=4" \
     python -m pytest -x -q tests/test_async_engine.py tests/test_slot_ring.py \
     tests/test_mc_sources_engine.py tests/test_collisions_engine.py
-python -m benchmarks.run --smoke --json BENCH_smoke.json \
-    --scaling-json BENCH_scaling.fresh.json
-
-# ---- perf gate ----
-python scripts/check_perf.py --scaling-baseline BENCH_scaling.json \
-    --scaling-fresh BENCH_scaling.fresh.json
 
 # ---- docs lane ----
 python scripts/check_links.py README.md docs
@@ -78,7 +63,7 @@ errs = validate_stream([header] + steps)
 assert not errs, errs
 print(f"metrics smoke: header + {len(steps)} valid step records")
 EOF
-rm -rf ci_profile_smoke ci_metrics_smoke.jsonl BENCH_scaling.fresh.json
+rm -rf ci_profile_smoke ci_metrics_smoke.jsonl
 
 # ---- resilience lane ----
 XLA_FLAGS="--xla_force_host_platform_device_count=4" python - <<'EOF'

@@ -58,7 +58,8 @@ def make_config(scale: int = 1, *, mover_strategy: str = "unified",
 def make_bench_config(nc: int = 4096, n: int = 262_144,
                       strategy: str = "unified",
                       diag_every: int = 1) -> pic.PICConfig:
-    """Laptop-scale version for the CPU benchmarks (same physics)."""
+    """Laptop-scale version (same physics): ``pic_run``'s default problem
+    and the tests'."""
     cap = 2 * n
     species = (
         pic.SpeciesConfig("e", -1.0, 1.0, cap, n, vth=1.0),
@@ -170,12 +171,12 @@ def make_collision_config(nc: int = 4096, n: int = 262_144,
 def make_engine_config(pic_cfg: pic.PICConfig | None = None, *,
                        async_n: int = 1, max_migration: int = 8192,
                        rebalance_every: int = 0, rebalance_skew: int = 0,
-                       max_births: int = 8192, use_ring: bool = True,
+                       max_births: int = 8192,
                        cell_order: bool = False, metrics: bool = False,
                        axis_names: tuple[str, ...] = ("data",),
                        **bench_kw):
     """EngineConfig for the asynchronous multi-device engine, centralizing
-    the queue-schedule knobs the launcher and benchmarks share.
+    the queue-schedule knobs the launcher and the tests share.
 
     ``async_n`` is the paper's async(n) queue count, ``max_migration`` the
     per-species/direction/step send budget, ``max_births`` the analogous
@@ -184,8 +185,7 @@ def make_engine_config(pic_cfg: pic.PICConfig | None = None, *,
     threshold that additionally triggers the re-split (0 = off);
     ``cell_order=True`` makes the rebalance a BIT1-style counting sort by
     cell (per-cell ordering for the collide phase and deposit locality).
-    ``use_ring=False`` selects the legacy full-capacity-scan merge (parity/
-    debug only). ``metrics=True`` adds the observability counters to the
+    ``metrics=True`` adds the observability counters to the
     step diagnostics (``repro.obs``; diagnostics-only, state unchanged).
     With no ``pic_cfg`` the CPU-scale bench config is built from
     ``bench_kw`` (see ``make_bench_config``).
@@ -198,4 +198,4 @@ def make_engine_config(pic_cfg: pic.PICConfig | None = None, *,
         pic=pic_cfg, axis_names=axis_names, async_n=async_n,
         max_migration=max_migration, max_births=max_births,
         rebalance_every=rebalance_every, rebalance_skew=rebalance_skew,
-        use_ring=use_ring, cell_order=cell_order, metrics=metrics)
+        cell_order=cell_order, metrics=metrics)

@@ -2,11 +2,10 @@
 
 The domain-decomposed PIC step moved to the asynchronous multi-device engine
 in ``repro/distributed/`` (async(n) queue scheduler in ``engine.py``,
-halo-exchange field phase in ``halo.py``, per-phase perf instrumentation in
-``perf.py``). This module keeps the seed's public API — ``DomainConfig``,
-``make_distributed_step``, ``init_distributed_state`` — delegating to the
-engine with ``async_n=1``, so existing callers (launcher, dry-run, tests)
-keep working unchanged.
+halo-exchange field phase in ``halo.py``). This module keeps the seed's
+public API — ``DomainConfig``, ``make_distributed_step``,
+``init_distributed_state`` — delegating to the engine with ``async_n=1``,
+so existing callers (launcher, dry-run, tests) keep working unchanged.
 
 Differences from the seed implementation, inherited from the engine:
 

@@ -43,9 +43,9 @@ MPI_Allgather (field)  eliminated: ``halo.py`` exchanges edge nodes with
                        ``ppermute`` and distributes the exact double-prefix
                        Poisson solve with scalar-only gathers
 Nsight phase ranges    ``repro.obs.tracing`` named scopes on every phase /
-                       queue stage / halo collective (Perfetto-visible), plus
-                       ``perf.phase_breakdown`` cumulative-checkpoint probes;
-                       speedup + PE tables in ``BENCH_scaling.json``
+                       queue stage / halo collective (Perfetto-visible; the
+                       benchmark's per-layer metrics read them from the
+                       compiled program)
 Online knob tuning     ``repro.obs.autotune`` consumes the per-step metrics
                        stream (``EngineConfig.metrics``) and retunes the
                        queue knobs via ``engine.retarget_state``
@@ -56,15 +56,11 @@ package (same DomainConfig / make_distributed_step / init_distributed_state
 API, async_n=1).
 """
 
-from repro.distributed.engine import (EngineConfig, EngineState, PHASES,
+from repro.distributed.engine import (EngineConfig, EngineState,
                                       attach_engine_state, init_engine_state,
                                       make_engine_step, retarget_state)
-from repro.distributed.perf import (phase_breakdown, queue_stats,
-                                    scaling_metrics, write_scaling_json)
 
 __all__ = [
-    "EngineConfig", "EngineState", "PHASES", "attach_engine_state",
-    "init_engine_state", "make_engine_step", "phase_breakdown",
-    "queue_stats", "retarget_state", "scaling_metrics",
-    "write_scaling_json",
+    "EngineConfig", "EngineState", "attach_engine_state",
+    "init_engine_state", "make_engine_step", "retarget_state",
 ]

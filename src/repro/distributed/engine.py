@@ -26,22 +26,21 @@ cell-binned elastic / charge-exchange / Coulomb pairing inside the queue
 slice — velocities only, so no ring traffic) -> in-queue MC ionization ->
 per-queue migration exchange + SEE -> deferred merge -> diagnostics psum.
 
-Free-slot ring (the merge-phase fix): the seed merge re-discovered dead
-slots with one full-capacity ``free_slots`` scan per species per step, so
-the ``merge`` probe time scaled with TOTAL capacity, not with the arrival
-count. The engine carries a persistent ``particles.FreeSlotRing`` per
-capacity group in its state: migration leavers and wall-absorbed particles
-push their (already-packed, O(max_migration)) slot indices, arrivals pop
-pre-claimed slots, and the scatter itself is **deferred into the next
-step's ingest** — the pass that is about to stream the whole buffer through
-the push anyway. The merge phase keeps only O(max_migration) ring
+Free-slot ring: a merge that re-discovers dead slots with one
+full-capacity ``free_slots`` scan per species per step costs time in TOTAL
+capacity, not in the arrival count. The engine carries a persistent
+``particles.FreeSlotRing`` per capacity group in its state: migration
+leavers and wall-absorbed particles push their (already-packed,
+O(max_migration)) slot indices, arrivals pop pre-claimed slots, and the
+scatter itself is **deferred into the next step's ingest** — the pass that
+is about to stream the whole buffer through the push anyway. The merge phase keeps only O(max_migration) ring
 bookkeeping plus the carried-rho arrival deposit. In-flight arrivals live
 in ``EngineState.pending`` and are counted by the step diagnostics, so
 conservation is exact at every step boundary.
 
 Monte-Carlo sources ride the same ring (this is what lets the paper's §3.3
 ionization scenario and the SEE plasma-wall source run on the async
-pipeline — no more legacy full-scan demotion):
+pipeline):
 
 * **ionization** runs per queue, between that queue's push and its
   migration exchange: ``collisions.ionize_packed`` draws events over the
@@ -65,16 +64,6 @@ particle count and charge bitwise at every step boundary. With
 ``strategy='fused'`` the birth charge is deposited into the carried rho at
 merge time (the same arrival-style correction migration uses), so the
 carried-rho fast path now covers MC-source runs with the field solve on.
-
-``EngineConfig.use_ring=False`` keeps the legacy full-capacity-scan merge
-as an opt-in debug/parity mode: the SAME MC events (identical keys) are
-injected through ``inject_masked`` scans instead — the conservation suite
-pins the two paths against each other on identical seeds. The parity
-holds while nothing drops: legacy mode retains the pre-PR-4 loss
-semantics at the margins (a full buffer at merge time drops a birth whose
-neutral was already killed, counted by ``merge_dropped``), whereas the
-ring path refuses the kill up front — run the ring path outside of parity
-tests.
 
 Queue-adaptive rebalance: the interleaved split is only even while
 occupancy is; absorption/ionization churn drifts the per-queue alive counts
@@ -115,7 +104,7 @@ from repro.core.grid import (Grid1D, deposit_density, deposit_stacked,
                              deposit_flat)
 from repro.core.particles import (FreeSlotRing, SpeciesBuffer, StackedSpecies,
                                   first_true_indices, init_uniform,
-                                  inject_at, inject_masked, kill,
+                                  inject_at, kill,
                                   kill_packed, ring_claim,
                                   ring_from_counts, ring_init, ring_push,
                                   sort_by_cell, stack_species, take)
@@ -127,15 +116,6 @@ from repro.kernels import ops
 from repro.obs import tracing
 
 Array = jax.Array
-
-# cumulative phase checkpoints for the perf probes (see perf.py): a step
-# built with upto=<phase> executes the pipeline through that phase and
-# returns, so consecutive differences give per-phase wall times. ``collide``
-# (the per-queue binary-collision menu, between each queue's push and its
-# migration exchange) split out of the old fused ``collide_diag`` tail when
-# the collision substrate landed.
-PHASES = ("ingest", "field", "push", "collide", "migrate", "merge", "full")
-
 
 @dataclasses.dataclass(frozen=True)
 class EngineConfig:
@@ -150,9 +130,7 @@ class EngineConfig:
     triggers the same compaction whenever per-queue occupancy skew exceeds
     T (0 disables): each capacity group is compacted (alive first) and the
     free-slot ring rebuilt, so per-queue occupancy skew stays bounded under
-    absorption/ionization churn. ``use_ring=False`` selects the legacy
-    full-capacity-scan merge — a debug/parity mode only (the conservation
-    suite pins it against the ring path on identical seeds).
+    absorption/ionization churn.
 
     ``metrics=True`` adds the observability counters to the step
     diagnostics — per-species free-slot-ring occupancy (``ring_free``) and
@@ -179,7 +157,6 @@ class EngineConfig:
     rebalance_every: int = 0             # 0 = never re-split periodically
     rebalance_skew: int = 0              # 0 = no skew-triggered re-split
     max_births: int = 2048               # ionization births per domain/step
-    use_ring: bool = True                # False: legacy full-scan merge
     cell_order: bool = False             # rebalance counting-sorts by cell
     metrics: bool = False                # extra diag for the metrics stream
 
@@ -264,8 +241,6 @@ class EngineState:
 
     ``rings`` / ``pending`` hold one entry per capacity group (matching
     ``_capacity_groups`` order), each batched over the group's species axis.
-    Both are empty tuples in the legacy full-scan mode
-    (``EngineConfig.use_ring=False``).
     """
 
     pic: PICState
@@ -459,19 +434,6 @@ def _exchange_queue(q, l_local: float, m: int, boundary: str,
     return jax.vmap(pack_one)(q.x, q.v, q.w, q.alive)
 
 
-def _inject_rows(full: SpeciesBuffer, cand: SpeciesBuffer):
-    """vmapped full-scan inject of (S, ncand) candidates into (S, cap)
-    buffers — the legacy merge used in the opt-in parity mode
-    (``use_ring=False``)."""
-
-    def one(bx, bv, bw, ba, cx, cv, cw, ca):
-        return inject_masked(SpeciesBuffer(x=bx, v=bv, w=bw, alive=ba),
-                             cx, cv, cw, ca)
-
-    return jax.vmap(one)(full.x, full.v, full.w, full.alive,
-                         cand.x, cand.v, cand.w, cand.alive)
-
-
 def _flush_pending(st: StackedSpecies, p: PendingArrivals) -> StackedSpecies:
     """Scatter pre-claimed arrivals into their ring-assigned slots, one
     species at a time. The slots were dead when claimed and nothing re-fills
@@ -499,8 +461,7 @@ def _birth_block(s: int, nb: int, cap: int, dtype,
     ``rows`` maps a species row j to its (x, v, w, ok, dest) candidate
     arrays — an ionization block carries the electron AND ion rows of the
     same events when the two species share a capacity group; every other
-    row stays dead. ``dest=None`` (legacy full-scan mode) leaves the drop
-    sentinel, which ``_inject_rows`` never reads."""
+    row stays dead (``dest`` at the drop sentinel)."""
     bx = jnp.zeros((s, nb), dtype)
     bv = jnp.zeros((s, nb, 3), dtype)
     bw = jnp.zeros((s, nb), dtype)
@@ -512,8 +473,7 @@ def _birth_block(s: int, nb: int, cap: int, dtype,
         bv = bv.at[j].set(v)
         bw = bw.at[j].set(w * ok)
         ba = ba.at[j].set(ok)
-        if dest is not None:
-            bd = bd.at[j].set(dest.astype(jnp.int32))
+        bd = bd.at[j].set(dest.astype(jnp.int32))
     return PendingArrivals(x=bx, v=bv, w=bw, alive=ba, dest=bd)
 
 
@@ -589,8 +549,6 @@ def _state_specs(ecfg: EngineConfig, mesh: Mesh) -> EngineState:
             SpeciesBuffer(x=part, v=part, w=part, alive=part)
             for _ in ecfg.pic.species),
         key=part, step=P(), rho=part if carried else None)
-    if not ecfg.use_ring:
-        return EngineState(pic=pic, rings=(), pending=())
     groups = _capacity_groups(ecfg, mesh)
     rings = tuple(FreeSlotRing(slots=part, head=part, count=part)
                   for _ in groups)
@@ -611,15 +569,12 @@ def _lift(species, key, step, rho) -> PICState:
         key=key[None], step=step, rho=rho)
 
 
-def make_engine_step(ecfg: EngineConfig, mesh: Mesh, *, upto: str = "full",
-                     donate: bool = True, with_params: bool = False):
-    """Build the shard_map'd async(n) PIC step.
-
-    ``upto='full'`` (default) returns the production step: jit-compiled,
-    state-donating, ``state -> (state, diag)``. Earlier values of ``upto``
-    build the perf probes (see ``PHASES``): the pipeline runs through that
-    phase and returns ``(state, aux)`` undonated, so cumulative differencing
-    yields per-phase times without instrumenting the hot path.
+def make_engine_step(ecfg: EngineConfig, mesh: Mesh, *, donate: bool = True,
+                     with_params: bool = False):
+    """Build the shard_map'd async(n) PIC step: jit-compiled,
+    ``state -> (state, diag)``, donating its input state unless
+    ``donate=False`` (what a caller that reads the step's jaxpr, or steps
+    one state twice, builds).
 
     ``with_params=True`` returns ``(state, params) -> (state, diag)`` taking
     a ``RuntimeParams`` pytree (replicated across domains) for the runtime
@@ -627,8 +582,6 @@ def make_engine_step(ecfg: EngineConfig, mesh: Mesh, *, upto: str = "full",
     parameter point of a sweep runs through ONE compiled step. Identical
     values are bit-identical to the static build (see ``core/params.py``).
     """
-    if upto not in PHASES:
-        raise ValueError(f"upto must be one of {PHASES}, got {upto!r}")
     cfg = ecfg.pic
     ncl = ecfg.local_nc(mesh)
     grid_local = Grid1D(nc=ncl, dx=cfg.dx)
@@ -638,7 +591,6 @@ def make_engine_step(ecfg: EngineConfig, mesh: Mesh, *, upto: str = "full",
     m_q = ecfg.queue_migration
     b_q = ecfg.queue_births if cfg.ionization is not None else 0
     carried = _carries_rho(ecfg)
-    use_ring = ecfg.use_ring
     reb_k = ecfg.rebalance_every
     skew_k = ecfg.rebalance_skew
     groups = _capacity_groups(ecfg, mesh)
@@ -719,11 +671,8 @@ def make_engine_step(ecfg: EngineConfig, mesh: Mesh, *, upto: str = "full",
                                       & (state.step % reb_k == 0))
             for g, idxs in enumerate(groups):
                 cap_g = group_caps[g]
-                if not (use_ring or reb_k > 0 or skew_k > 0):
-                    continue
-                st = stack_species([species[i] for i in idxs])
-                if use_ring:
-                    st = _flush_pending(st, pend_in[g])
+                st = _flush_pending(
+                    stack_species([species[i] for i in idxs]), pend_in[g])
                 reb_g = rebalance_periodic
                 if skew_k > 0:
                     occ = jax.vmap(
@@ -739,30 +688,22 @@ def make_engine_step(ecfg: EngineConfig, mesh: Mesh, *, upto: str = "full",
                     sort_group = (
                         (lambda s: _cellsort_group(s, cfg.dx, ncl))
                         if ecfg.cell_order else _compact_group)
-                    if use_ring:
-                        def reb(op):
-                            new, counts = sort_group(op[0])
-                            return new, jax.vmap(
-                                lambda c: ring_from_counts(c, cap_g))(counts)
 
-                        st, rings[g] = jax.lax.cond(
-                            reb_g, reb, lambda op: op, (st, rings[g]))
-                    else:
-                        st = jax.lax.cond(
-                            reb_g, lambda s: sort_group(s)[0],
-                            lambda s: s, st)
+                    def reb(op):
+                        new, counts = sort_group(op[0])
+                        return new, jax.vmap(
+                            lambda c: ring_from_counts(c, cap_g))(counts)
+
+                    st, rings[g] = jax.lax.cond(
+                        reb_g, reb, lambda op: op, (st, rings[g]))
                 write_back(idxs, st)
         with tracing.phase_scope("engine/state"):
             empty_pend = [
                 _empty_pending(len(idxs), prows[g], group_caps[g],
                                species[idxs[0]].x.dtype)
-                for g, idxs in enumerate(groups)] if use_ring else []
+                for g, idxs in enumerate(groups)]
             rho_acc = (jnp.zeros((ncl + 1,), jnp.float32) if carried
                        else None)
-        if upto == "ingest":
-            aux = sum(jnp.sum(b.alive.astype(jnp.float32))
-                      for b in species).reshape(1)
-            return pack_state(state.rho, empty_pend), aux
 
         # ---- field phase: halo exchange, never a full-rho all_gather ----
         with tracing.phase_scope("engine/field"):
@@ -783,8 +724,6 @@ def make_engine_step(ecfg: EngineConfig, mesh: Mesh, *, upto: str = "full",
                     smoothing_passes=cfg.smoothing_passes,
                     axis_names=axis_names, mesh=mesh, is_first=is_first,
                     is_last=is_last)
-        if upto == "field":
-            return pack_state(state.rho, empty_pend), e[None]
 
         diag: dict = {}
 
@@ -794,9 +733,7 @@ def make_engine_step(ecfg: EngineConfig, mesh: Mesh, *, upto: str = "full",
 
         # ---- MC source inputs: one electron-density deposit (halo-summed
         #      at the shared edge nodes, the periodic wrap included) and
-        #      per-queue event keys, derived identically in ring and legacy
-        #      modes so the two paths draw the same physics from the same
-        #      seed ----
+        #      per-queue event keys ----
         ne_local = None
         iparams = eparams = None
         ion_keys = see_keys = None
@@ -880,11 +817,6 @@ def make_engine_step(ecfg: EngineConfig, mesh: Mesh, *, upto: str = "full",
                     for j, sc in enumerate(scs):
                         for k, v in pdiag.items():
                             dacc(sc.name, k, v[j])
-                if upto == "push":
-                    if carried:
-                        rho_acc = rho_push      # keep the in-pass deposit
-                    kept_qs.append(out)         # live in the probe output
-                    continue
 
                 # ---- binary collisions on this queue (before the MC
                 #      sources and the exchange): the menu runs on the
@@ -918,11 +850,6 @@ def make_engine_step(ecfg: EngineConfig, mesh: Mesh, *, upto: str = "full",
                                 alive=out.alive)
                         for ck, cv in cdiag.items():
                             dacc(None, ck, cv)
-                if upto == "collide":
-                    if carried:
-                        rho_acc = rho_push
-                    kept_qs.append(out)
-                    continue
 
                 # ---- MC ionization on this queue (before the exchange, so
                 #      ionized neutrals are never packed as crossers) ----
@@ -940,41 +867,35 @@ def make_engine_step(ecfg: EngineConfig, mesh: Mesh, *, upto: str = "full",
                                 ne_local, b_q)
                         with tracing.phase_scope("births"):
                             (ge, je), (gi, ji) = loc[ei], loc[ii]
-                            if use_ring:
-                                # pre-claim one electron + one ion slot per
-                                # birth under the shared min-count budget: a
-                                # birth gets both slots or neither (no half
-                                # pairs, no leaks)
-                                if ge == gi:
-                                    avail = jnp.minimum(rings[ge].count[je],
-                                                        rings[ge].count[ji])
-                                    rings[ge], dest, okm = _claim_rows(
-                                        rings[ge],
-                                        {je: pack.ok, ji: pack.ok},
-                                        group_caps[ge], avail)
-                                    allowed = okm[je]
-                                    dest_e, dest_i = dest[je], dest[ji]
-                                else:
-                                    avail = jnp.minimum(rings[ge].count[je],
-                                                        rings[gi].count[ji])
-                                    rings[ge], de, oe = _claim_rows(
-                                        rings[ge], {je: pack.ok},
-                                        group_caps[ge], avail)
-                                    rings[gi], di, _ = _claim_rows(
-                                        rings[gi], {ji: pack.ok},
-                                        group_caps[gi], avail)
-                                    allowed = oe[je]
-                                    dest_e, dest_i = de[je], di[ji]
-                                # freed neutral slots feed the ring like
-                                # leavers (queue slot j -> global slot
-                                # j * n_q + k_q)
-                                rings[g] = _push_rows(
-                                    rings[g],
-                                    {jn: (pack.slot * n_q + k_q, allowed)},
-                                    b_q)
+                            # pre-claim one electron + one ion slot per
+                            # birth under the shared min-count budget: a
+                            # birth gets both slots or neither (no half
+                            # pairs, no leaks)
+                            if ge == gi:
+                                avail = jnp.minimum(rings[ge].count[je],
+                                                    rings[ge].count[ji])
+                                rings[ge], dest, okm = _claim_rows(
+                                    rings[ge], {je: pack.ok, ji: pack.ok},
+                                    group_caps[ge], avail)
+                                allowed = okm[je]
+                                dest_e, dest_i = dest[je], dest[ji]
                             else:
-                                allowed = pack.ok
-                                dest_e = dest_i = None
+                                avail = jnp.minimum(rings[ge].count[je],
+                                                    rings[gi].count[ji])
+                                rings[ge], de, oe = _claim_rows(
+                                    rings[ge], {je: pack.ok},
+                                    group_caps[ge], avail)
+                                rings[gi], di, _ = _claim_rows(
+                                    rings[gi], {ji: pack.ok},
+                                    group_caps[gi], avail)
+                                allowed = oe[je]
+                                dest_e, dest_i = de[je], di[ji]
+                            # freed neutral slots feed the ring like
+                            # leavers (queue slot j -> global slot
+                            # j * n_q + k_q)
+                            rings[g] = _push_rows(
+                                rings[g],
+                                {jn: (pack.slot * n_q + k_q, allowed)}, b_q)
                             killed = kill_packed(qn, pack.slot, allowed)
                             out = StackedSpecies(
                                 x=out.x.at[jn].set(killed.x),
@@ -1014,13 +935,12 @@ def make_engine_step(ecfg: EngineConfig, mesh: Mesh, *, upto: str = "full",
                         with tracing.phase_scope("deposit"):
                             rho_acc = rho_push - deposit_flat(
                                 grid_local, lv_x, charges[:, None] * lv_w)
-                    if use_ring:
-                        # leaver slots are free from here on: feed the ring
-                        # from the already-packed indices (queue slot j ->
-                        # global slot j * n_q + k_q), no extra scan
-                        with tracing.phase_scope("ring"):
-                            rings[g] = jax.vmap(ring_push)(
-                                rings[g], free_idx * n_q + k_q, free_ok)
+                    # leaver slots are free from here on: feed the ring
+                    # from the already-packed indices (queue slot j ->
+                    # global slot j * n_q + k_q), no extra scan
+                    with tracing.phase_scope("ring"):
+                        rings[g] = jax.vmap(ring_push)(
+                            rings[g], free_idx * n_q + k_q, free_ok)
 
                     # ---- SEE: yield-thinned secondaries off this queue's
                     #      absorbed rows (already packed by the exchange) --
@@ -1034,12 +954,9 @@ def make_engine_step(ecfg: EngineConfig, mesh: Mesh, *, upto: str = "full",
                                     see_keys[pi, k_q], abs_l[jp], abs_r[jp],
                                     eparams, l_local, dtype)
                             gt, jt = loc[t]
-                            if use_ring:
-                                rings[gt], dstm, okm = _claim_rows(
-                                    rings[gt], {jt: emit}, group_caps[gt])
-                                ok_t, dest_t = okm[jt], dstm[jt]
-                            else:
-                                ok_t, dest_t = emit, None
+                            rings[gt], dstm, okm = _claim_rows(
+                                rings[gt], {jt: emit}, group_caps[gt])
+                            ok_t, dest_t = okm[jt], dstm[jt]
                             birth_blocks[gt].append(_birth_block(
                                 len(groups[gt]), 2 * m_q, group_caps[gt],
                                 dtype, {jt: (ex, ev, ew, ok_t, dest_t)}))
@@ -1061,25 +978,11 @@ def make_engine_step(ecfg: EngineConfig, mesh: Mesh, *, upto: str = "full",
                             dacc(sc.name, k, v[j])
             staged.append((idxs, charges, kept_qs, pending_packs))
 
-        if upto in ("push", "collide", "migrate"):
-            aux = e
-            for idxs, _, kept_qs, pending_packs in staged:
-                write_back(idxs, _merge_queues(kept_qs, n_q))
-                # keep the received packs live in the probe output so the
-                # migration collectives are not dead-code-eliminated
-                for recv in pending_packs:
-                    for leaf in jax.tree.leaves(recv):
-                        aux = aux + jnp.sum(leaf.astype(jnp.float32))
-            rho_out = rho_acc[None] if carried else state.rho
-            return pack_state(rho_out, empty_pend), aux[None]
-
         # ---- deferred merge: every queue's collective has been issued.
-        #      Ring path: claim a dead slot per arrival from the free-slot
-        #      ring (O(max_migration)), append the queues' birth blocks
-        #      (slots already claimed), and carry the rows as pending — the
-        #      scatter happens at the NEXT step's ingest. Legacy path
-        #      (use_ring=False): one full-capacity free-slot scan per
-        #      species over arrivals AND births, scattered immediately ----
+        #      Claim a dead slot per arrival from the free-slot ring
+        #      (O(max_migration)), append the queues' birth blocks (slots
+        #      already claimed), and carry the rows as pending — the
+        #      scatter happens at the NEXT step's ingest ----
         pend_out = list(empty_pend)
         with tracing.phase_scope("engine/merge"):
             for g, (idxs, charges, kept_qs,
@@ -1088,52 +991,32 @@ def make_engine_step(ecfg: EngineConfig, mesh: Mesh, *, upto: str = "full",
                 cap_g = group_caps[g]
                 # the kept queues back in slot order
                 with tracing.phase_scope("layout"):
-                    full = _merge_queues(kept_qs, n_q)
-                    if use_ring:
-                        write_back(idxs, full)
+                    write_back(idxs, _merge_queues(kept_qs, n_q))
                 with tracing.phase_scope("claim"):
                     packs = [p for pair in pending_packs for p in pair]
                     cand = jax.tree.map(
                         lambda *xs: jnp.concatenate(xs, axis=1), *packs)
-                    if use_ring:
-                        rings[g], dest, accepted = jax.vmap(
-                            lambda rg, wnt: ring_claim(rg, wnt, cap_g))(
-                            rings[g], cand.alive)
-                        blocks = [PendingArrivals(
-                            x=cand.x, v=cand.v, w=cand.w * accepted,
-                            alive=cand.alive & accepted, dest=dest)]
-                        blocks += birth_blocks[g]
-                        pend_g = (blocks[0] if len(blocks) == 1
-                                  else jax.tree.map(
-                                      lambda *xs: jnp.concatenate(xs, axis=1),
-                                      *blocks))
-                        pend_out[g] = pend_g
-                        dropped = jnp.sum(
-                            (cand.alive & ~accepted).astype(jnp.int32),
-                            axis=1)
-                        if carried:
-                            rho_acc = rho_acc + deposit_flat(
-                                grid_local, pend_g.x,
-                                charges[:, None] * pend_g.w * pend_g.alive)
-                    else:
-                        extra = [SpeciesBuffer(x=b.x, v=b.v, w=b.w,
-                                               alive=b.alive)
-                                 for b in birth_blocks[g]]
-                        cand_all = cand if not extra else jax.tree.map(
-                            lambda *xs: jnp.concatenate(xs, axis=1), cand,
-                            *extra)
-                        merged, dropped, accepted = _inject_rows(full,
-                                                                 cand_all)
-                        write_back(idxs, merged)
-                        if carried:
-                            rho_acc = rho_acc + deposit_flat(
-                                grid_local, cand_all.x,
-                                charges[:, None] * cand_all.w * accepted)
+                    rings[g], dest, accepted = jax.vmap(
+                        lambda rg, wnt: ring_claim(rg, wnt, cap_g))(
+                        rings[g], cand.alive)
+                    blocks = [PendingArrivals(
+                        x=cand.x, v=cand.v, w=cand.w * accepted,
+                        alive=cand.alive & accepted, dest=dest)]
+                    blocks += birth_blocks[g]
+                    pend_g = (blocks[0] if len(blocks) == 1
+                              else jax.tree.map(
+                                  lambda *xs: jnp.concatenate(xs, axis=1),
+                                  *blocks))
+                    pend_out[g] = pend_g
+                    dropped = jnp.sum(
+                        (cand.alive & ~accepted).astype(jnp.int32), axis=1)
+                    if carried:
+                        rho_acc = rho_acc + deposit_flat(
+                            grid_local, pend_g.x,
+                            charges[:, None] * pend_g.w * pend_g.alive)
                     for j, sc in enumerate(scs):
                         dacc(sc.name, "merge_dropped", dropped[j])
         rho_out = rho_acc[None] if carried else state.rho
-        if upto == "merge":
-            return pack_state(rho_out, pend_out), e[None]
 
         # ---- global diagnostics (psum over domains; skew uses pmax) ----
         # in-flight arrivals and births are resident particles: reduce over
@@ -1144,15 +1027,12 @@ def make_engine_step(ecfg: EngineConfig, mesh: Mesh, *, upto: str = "full",
         # the engine's exact cross-D conservation contract.
         with tracing.phase_scope("engine/diag"):
             eff = list(species)
-            if use_ring:
-                for g, idxs in enumerate(groups):
-                    st = _flush_pending(
-                        stack_species([species[i] for i in idxs]),
-                        pend_out[g])
-                    for j, i in enumerate(idxs):
-                        eff[i] = SpeciesBuffer(
-                            x=st.x[j], v=st.v[j], w=st.w[j],
-                            alive=st.alive[j])
+            for g, idxs in enumerate(groups):
+                st = _flush_pending(
+                    stack_species([species[i] for i in idxs]), pend_out[g])
+                for j, i in enumerate(idxs):
+                    eff[i] = SpeciesBuffer(
+                        x=st.x[j], v=st.v[j], w=st.w[j], alive=st.alive[j])
             for sc, buf in zip(cfg.species, eff):
                 diag[f"{sc.name}/count"] = buf.count()
                 diag[f"{sc.name}/ke"] = diagnostics.kinetic_energy(
@@ -1162,7 +1042,7 @@ def make_engine_step(ecfg: EngineConfig, mesh: Mesh, *, upto: str = "full",
                 occ = _queue_occupancy(buf.alive, n_q)
                 diag[f"{sc.name}/queue_occ"] = occ
                 diag[f"{sc.name}/queue_skew"] = jnp.max(occ) - jnp.min(occ)
-            if ecfg.metrics and use_ring:
+            if ecfg.metrics:
                 # observability extras (diagnostics-only — the state math
                 # is untouched, so metrics on/off stays bitwise identical):
                 # free-slot-ring occupancy and in-flight pending rows, the
@@ -1181,9 +1061,8 @@ def make_engine_step(ecfg: EngineConfig, mesh: Mesh, *, upto: str = "full",
         return pack_state(rho_out, pend_out), diag
 
     specs_state = _state_specs(ecfg, mesh)
-    out_specs = ((specs_state, P()) if upto == "full"
-                 else (specs_state, P(axis_names)))
-    donate_kw = {"donate_argnums": (0,)} if (donate and upto == "full") else {}
+    out_specs = (specs_state, P())
+    donate_kw = {"donate_argnums": (0,)} if donate else {}
     if with_params:
         # runtime params ride replicated (P() on every leaf): each domain
         # reads the same scalars, nothing is ever sharded or donated
@@ -1224,8 +1103,6 @@ def attach_engine_state(ecfg: EngineConfig, mesh: Mesh,
     Use this to feed the engine a state produced by ``pic.init_state`` (via
     the usual ``[None]`` lift) or by an older checkpoint.
     """
-    if not ecfg.use_ring:
-        return EngineState(pic=state, rings=(), pending=())
 
     def local(st: PICState) -> EngineState:
         bufs = [jax.tree.map(lambda a: a[0], b) for b in st.species]
@@ -1265,26 +1142,23 @@ def retarget_state(old: EngineConfig, new: EngineConfig, mesh: Mesh,
             "(EngineConfig.pic) must be identical")
     groups_old = _capacity_groups(old, mesh)
     groups_new = _capacity_groups(new, mesh)
-    if (old.use_ring == new.use_ring and groups_old == groups_new
+    if (groups_old == groups_new
             and _group_pending_rows(old, groups_old)
             == _group_pending_rows(new, groups_new)):
         return state  # same pytree shape: the next compile picks it up
 
     def local(est: EngineState) -> EngineState:
         bufs = [jax.tree.map(lambda a: a[0], b) for b in est.pic.species]
-        if old.use_ring:
-            pend_in = [jax.tree.map(lambda a: a[0], p) for p in est.pending]
-            for g, idxs in enumerate(groups_old):
-                st = _flush_pending(
-                    stack_species([bufs[i] for i in idxs]), pend_in[g])
-                for j, i in enumerate(idxs):
-                    bufs[i] = SpeciesBuffer(x=st.x[j], v=st.v[j], w=st.w[j],
-                                            alive=st.alive[j])
+        pend_in = [jax.tree.map(lambda a: a[0], p) for p in est.pending]
+        for g, idxs in enumerate(groups_old):
+            st = _flush_pending(
+                stack_species([bufs[i] for i in idxs]), pend_in[g])
+            for j, i in enumerate(idxs):
+                bufs[i] = SpeciesBuffer(x=st.x[j], v=st.v[j], w=st.w[j],
+                                        alive=st.alive[j])
         pic_out = PICState(species=tuple(_lift_tree(b) for b in bufs),
                            key=est.pic.key, step=est.pic.step,
                            rho=est.pic.rho)
-        if not new.use_ring:
-            return EngineState(pic=pic_out, rings=(), pending=())
         rings, pending = _engine_extras(new, mesh, bufs)
         return EngineState(
             pic=pic_out, rings=tuple(_lift_tree(rg) for rg in rings),
@@ -1305,7 +1179,6 @@ def init_engine_state(ecfg: EngineConfig, mesh: Mesh,
     l_local = ncl * cfg.dx
     d = ecfg.num_domains(mesh)
     carried = _carries_rho(ecfg)
-    use_ring = ecfg.use_ring
     groups = _capacity_groups(ecfg, mesh)
 
     def local_init() -> EngineState:
@@ -1330,8 +1203,6 @@ def init_engine_state(ecfg: EngineConfig, mesh: Mesh,
                     grid_local, st.x, st.w, st.alive, charges)
         pic = _lift(bufs, keys[-1], jnp.zeros((), jnp.int32),
                     rho[None] if carried else None)
-        if not use_ring:
-            return EngineState(pic=pic, rings=(), pending=())
         rings, pending = _engine_extras(ecfg, mesh, bufs)
         return EngineState(
             pic=pic, rings=tuple(_lift_tree(rg) for rg in rings),
@@ -1379,7 +1250,9 @@ def resplit_host(ecfg: EngineConfig, mesh: Mesh,
     pending row into its pre-claimed slot (exactly the scatter the next
     ingest would have done), globalize positions, reassign each alive
     particle to its new domain by position, and compact per new domain
-    (alive first, stable checkpoint order within a domain).
+    (alive first, stable checkpoint order within a domain). A checkpoint
+    may hold no ``pending/*`` leaves (one written before the engine
+    carried in-flight rows): its buffers are then read as they are.
 
     Returns ``(species, counts)``: per-species dicts of ``(D', cap')``
     host arrays plus a ``(D', S)`` alive-count matrix — the closed-form
@@ -1404,7 +1277,7 @@ def resplit_host(ecfg: EngineConfig, mesh: Mesh,
                      for f in ("x", "v", "w", "alive")})
     for g, idxs in enumerate(_capacity_groups_d(ecfg, d_old)):
         if f"pending/{g}/dest" not in flat:
-            continue                      # legacy (use_ring=False) ckpt
+            continue                      # nothing in flight to land
         pend = {f: np.asarray(flat[f"pending/{g}/{f}"])
                 for f in ("x", "v", "w", "alive", "dest")}
         for j, i in enumerate(idxs):
@@ -1495,8 +1368,6 @@ def elastic_state(ecfg: EngineConfig, mesh: Mesh, species, counts,
                     grid_local, st.x, st.w, st.alive, charges)
         pic = _lift(bufs, key, jnp.asarray(step_c, jnp.int32),
                     rho[None] if carried else None)
-        if not ecfg.use_ring:
-            return EngineState(pic=pic, rings=(), pending=())
         rings, pending = [], []
         for g, idxs in enumerate(groups):
             st = stack_species([bufs[i] for i in idxs])

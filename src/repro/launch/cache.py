@@ -1,7 +1,7 @@
 """Where JAX keeps its persistent compilation cache.
 
 Every entry point (``chip_smoke.py``, ``repro.launch.pic_run``,
-``benchmarks.run``) calls ``enable_compilation_cache`` before it compiles
+``chipbench/run.py``) calls ``enable_compilation_cache`` before it compiles
 anything, so a later process with the same program finds the executables
 an earlier one wrote. The directory is part of what makes a hit, so it is
 fixed: ``JAX_COMPILATION_CACHE_DIR`` when the environment sets it (JAX reads

@@ -10,7 +10,7 @@ engine:
   (the Nsight-range analogue: the names land in the XLA op metadata and
   show up in Perfetto/TensorBoard traces), ``TraceAnnotation`` host spans,
   and ``trace_session`` capture around a run
-  (``pic_run --profile-dir``, ``benchmarks.run --profile-dir``);
+  (``pic_run --profile-dir``);
 * ``metrics``  — a structured per-step metrics stream (JSONL run report +
   in-memory ring) collecting what the engine already computes but used to
   drop: queue occupancy/skew, migration/birth/emission overflows,

@@ -22,9 +22,8 @@ optionally to a JSONL file: line 1 is a header record (``kind: "header"``,
 schema version, free-form ``config``), every later line one step record
 (``kind: "step"``). ``validate_record`` is the schema the tests pin.
 
-``atomic_write_json`` is the shared write-temp-then-rename helper for the
-``BENCH_*.json`` artifacts: an interrupted benchmark can no longer truncate
-a committed trajectory file.
+``atomic_write_json`` writes a JSON file by temp file and rename, so an
+interrupted writer never leaves a truncated file behind.
 """
 
 from __future__ import annotations

@@ -9,13 +9,13 @@ Two kinds of range, matching the two places time is spent:
   paper's ``nvtxRangePush`` phase ranges. Engine phases use ``engine/<phase>``
   names, per-queue pipeline stages ``engine/<phase>/q<k>``, halo/field
   collectives ``halo/<op>``.
-* ``host_span(name)`` — used in HOST code (step loops, probes, benchmark
+* ``host_span(name)`` — used in HOST code (step loops, benchmark
   harnesses). Wraps ``jax.profiler.TraceAnnotation``, which emits a range on
   the host track of a captured trace.
 
 ``trace_session(profile_dir)`` brackets a run with
 ``jax.profiler.start_trace`` / ``stop_trace`` — the capture behind
-``pic_run --profile-dir`` and ``benchmarks.run --profile-dir``; open the
+``pic_run --profile-dir``; open the
 resulting ``plugins/profile/*`` in TensorBoard or the ``*.trace.json.gz``
 in Perfetto (ui.perfetto.dev).
 

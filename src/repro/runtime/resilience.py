@@ -49,8 +49,7 @@ def save_engine(ckpt: Checkpointer, ecfg: engine.EngineConfig, mesh: Mesh,
     so ``resume_engine`` can decide typed-vs-elastic without a config
     side-channel. Returns the ``Checkpointer.save`` info dict."""
     meta = {"kind": "engine", "domains": ecfg.num_domains(mesh),
-            "async_n": ecfg.async_n, "nc": ecfg.pic.nc,
-            "use_ring": ecfg.use_ring, "step": int(step)}
+            "async_n": ecfg.async_n, "nc": ecfg.pic.nc, "step": int(step)}
     return ckpt.save(step, state, blocking=blocking, meta=meta)
 
 

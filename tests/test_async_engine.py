@@ -13,6 +13,7 @@ import sys
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core import fields, pic
 from repro.distributed import engine, halo
@@ -86,9 +87,11 @@ def test_overflow_keeps_particles():
     assert sums["e/merge_dropped"] == 0
 
 
-def test_engine_matches_single_domain_reference():
+@pytest.mark.parametrize("async_n", [1, 2, 4])
+def test_engine_matches_single_domain_reference(async_n):
     """D=1 engine vs the plain fused hot loop, from the SAME initial state:
-    population and charge exact, energy equal to float tolerance."""
+    population and charge exact, energy equal to float tolerance, for
+    every queue count."""
     cfg = _cfg(nc=128, n=2048, cap=4096)
     state0 = pic.init_state(cfg, 7)
     ref_state, _ = jax.block_until_ready(pic.run(cfg, 15, state=state0))
@@ -99,8 +102,8 @@ def test_engine_matches_single_domain_reference():
         for sc, b in zip(cfg.species, ref_state.species)]
 
     mesh = make_debug_mesh(data=1, model=1)
-    ecfg = engine.EngineConfig(pic=cfg, axis_names=("data",), async_n=2,
-                               max_migration=512)
+    ecfg = engine.EngineConfig(pic=cfg, axis_names=("data",),
+                               async_n=async_n, max_migration=512)
     state0 = pic.init_state(cfg, 7)              # rebuild: ref run donated it
     est = pic.PICState(
         species=tuple(jax.tree.map(lambda a: a[None], b)
@@ -119,7 +122,6 @@ def test_engine_matches_single_domain_reference():
 
 
 def test_async_n_must_divide_budget_and_capacity():
-    import pytest
     with pytest.raises(ValueError):
         engine.EngineConfig(pic=_cfg(), async_n=3, max_migration=1024)
     mesh = make_debug_mesh(data=1, model=1)

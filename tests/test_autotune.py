@@ -10,6 +10,7 @@ import sys
 
 import jax
 import numpy as np
+import pytest
 
 from repro.configs.pic_bit1 import (make_engine_config, make_see_config)
 from repro.core import pic
@@ -22,16 +23,17 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 HERE = os.path.dirname(__file__)
 
 
-def _dispatch(func_name: str) -> None:
+def _dispatch(func_name: str, *args) -> None:
     """Run a check in-process when 4 devices exist, else in a subprocess
     with emulated host devices (same idiom as ``test_async_engine``)."""
     if jax.device_count() >= 4:
-        globals()[func_name]()
+        globals()[func_name](*args)
         return
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.abspath(SRC) + os.pathsep + HERE
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-    prog = f"from test_autotune import {func_name}; {func_name}()"
+    prog = (f"from test_autotune import {func_name}; "
+            f"{func_name}(*{args!r})")
     out = subprocess.run([sys.executable, "-c", prog], env=env,
                          capture_output=True, text=True, timeout=900)
     assert out.returncode == 0, out.stderr[-3000:]
@@ -121,7 +123,13 @@ def test_decide_arms_rebalance_on_skew():
 # ---------------------------------------------------------- state carry-over
 
 
-def retarget_flush_check():
+# the budget retunes that resize EngineState.pending: each alone and both
+RETUNES = {"max_migration": dict(max_migration=128),
+           "max_births": dict(max_births=256),
+           "both": dict(max_migration=128, max_births=256)}
+
+
+def retarget_flush_check(retune: str):
     """A budget retune mid-run must conserve every particle — including the
     in-flight pending arrivals/births the merge deferred to the next step's
     ingest. Counts are compared before/after the flush+rebuild. Needs D=2:
@@ -142,7 +150,7 @@ def retarget_flush_check():
 
     alive0, pend0 = totals(state)
     assert pend0 > 0, "workload produced no in-flight rows; test is vacuous"
-    new = dataclasses.replace(ecfg, max_migration=128, max_births=256)
+    new = dataclasses.replace(ecfg, **RETUNES[retune])
     state2 = engine.retarget_state(ecfg, new, mesh, state)
     alive1, pend1 = totals(state2)
     assert pend1 == 0                    # rebuilt pending starts empty
@@ -156,8 +164,9 @@ def retarget_flush_check():
             assert np.allclose(np.asarray(diag1[k]), np.asarray(diag2[k])), k
 
 
-def test_retarget_state_flushes_pending_exactly():
-    _dispatch("retarget_flush_check")
+@pytest.mark.parametrize("retune", list(RETUNES))
+def test_retarget_state_flushes_pending_exactly(retune):
+    _dispatch("retarget_flush_check", retune)
 
 
 def test_retarget_state_identity_when_compatible():

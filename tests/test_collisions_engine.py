@@ -14,8 +14,9 @@ KE under charge exchange) must hold on both paths. These tests pin
   gathers — no sort and no cumsum over a full-capacity axis (the
   ``test_slot_ring`` assertion style), and no non-scalar all_gather when
   the field solve is on;
-* cell_order=True: the rebalance really is a counting sort by cell (probed
-  at the ingest boundary), the free-slot-ring invariant survives it, and
+* cell_order=True: the rebalance really is a counting sort by cell (the
+  rebalance's sort applied to the buffers the next ingest sees), the
+  free-slot-ring invariant survives it, and
   conservation holds with collisions + ionization + SEE all active;
 * the ``EmissionParams.weight`` config satellite: mixed-weight SEE
   conserves charge exactly on both paths.
@@ -30,12 +31,14 @@ import subprocess
 import sys
 
 import numpy as np
+import pytest
 
 import jax
 import jax.numpy as jnp
 
 from repro.core import pic
 from repro.core.collisions import CollisionConfig
+from repro.core.particles import stack_species
 from repro.distributed import engine
 from repro.launch.mesh import make_debug_mesh
 
@@ -189,28 +192,39 @@ def test_collision_kernel_path_engine_parity():
     _assert_ke_invariants(ediag, efirst, "kernel")
 
 
-def test_cell_order_rebalance_counting_sorts():
+@pytest.mark.parametrize("async_n", [1, 2, 4])
+def test_cell_order_rebalance_counting_sorts(async_n):
     """With cell_order=True the rebalance orders every species buffer by
-    cell (live rows grouped, nondecreasing, dead at the tail) — probed at
-    the ingest checkpoint right after a rebalance boundary."""
+    cell (live rows grouped, nondecreasing, dead at the tail): after one
+    real step, each capacity group as the next ingest sees it (pending
+    rows landed) goes through the rebalance's sort,
+    ``engine._cellsort_group``, and keeps every particle."""
     cfg = _coll_cfg()
     mesh = make_debug_mesh(data=1, model=1)
-    ecfg = engine.EngineConfig(pic=cfg, axis_names=("data",), async_n=2,
-                               max_migration=512, max_births=512,
-                               rebalance_every=1, cell_order=True)
+    ecfg = engine.EngineConfig(pic=cfg, axis_names=("data",),
+                               async_n=async_n, max_migration=512,
+                               max_births=512, rebalance_every=1,
+                               cell_order=True)
     state = engine.init_engine_state(ecfg, mesh, 0)
     step = engine.make_engine_step(ecfg, mesh)
-    state, _ = step(state)                  # step -> 1: next ingest sorts
-    probe = engine.make_engine_step(ecfg, mesh, upto="ingest", donate=False)
-    sorted_state, _ = probe(state)
-    for i, sc in enumerate(cfg.species):
-        buf = jax.tree.map(lambda a: np.asarray(a)[0],
-                           sorted_state.pic.species[i])
-        n_live = int(buf.alive.sum())
-        assert n_live > 0
-        assert not buf.alive[n_live:].any(), sc.name      # dead tail
-        cells = np.floor(buf.x[:n_live] / cfg.dx).astype(int)
-        assert (np.diff(cells) >= 0).all(), sc.name       # cell-grouped
+    state, diag = step(state)               # step -> 1: next ingest sorts
+    sort = jax.jit(lambda st: engine._cellsort_group(
+        st, cfg.dx, ecfg.local_nc(mesh)))
+    for g, idxs in enumerate(engine._capacity_groups(ecfg, mesh)):
+        st = engine._flush_pending(
+            stack_species([jax.tree.map(lambda a: a[0],
+                                        state.pic.species[i])
+                           for i in idxs]),
+            jax.tree.map(lambda a: a[0], state.pending[g]))
+        out, counts = jax.tree.map(np.asarray, sort(st))
+        for j, i in enumerate(idxs):
+            name = cfg.species[i].name
+            n_live = int(out.alive[j].sum())
+            assert n_live == int(counts[j]) > 0, name
+            assert n_live == int(np.asarray(diag[f"{name}/count"])), name
+            assert not out.alive[j][n_live:].any(), name   # dead tail
+            cells = np.floor(out.x[j][:n_live] / cfg.dx).astype(int)
+            assert (np.diff(cells) >= 0).all(), name       # cell-grouped
 
 
 def test_cell_order_keeps_ring_invariant_with_all_sources():

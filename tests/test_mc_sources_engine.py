@@ -9,8 +9,9 @@ tests pin
 * count + charge conservation, bitwise-exact, for ionization and SEE
   across D in {1, 2, 4} x async_n in {1, 2, 4} x {rebalance on, off},
   with and without the field solve;
-* parity of the ring path against the legacy full-scan merge
-  (``EngineConfig.use_ring=False``) on identical seeds;
+* the ring's landing of each step's pending rows (arrivals and births in
+  their pre-claimed slots) against a full-capacity scan that injects the
+  same rows into the first dead slots;
 * the ``birth_overflow`` budget clamp (mirroring ``migration_overflow``):
   refused births leave the neutral alive to retry — never a lost particle;
 * the carried-rho fast path with MC sources active (birth charge folded
@@ -34,6 +35,7 @@ import numpy as np
 import pytest
 
 from repro.core import pic
+from repro.core.particles import stack_species
 from repro.distributed import engine
 from repro.launch.mesh import make_debug_mesh
 
@@ -98,25 +100,32 @@ _SOURCE_SUFFIXES = ("migration_overflow", "merge_dropped", "wall_absorbed",
                     "migrated_right")
 
 
+def _accumulate(sums: dict, diag) -> None:
+    for k, v in diag.items():
+        if k in _SOURCE_KEYS or k.endswith(_SOURCE_SUFFIXES):
+            sums[k] = sums.get(k, 0) + int(np.asarray(v))
+
+
+def _host_diag(diag) -> dict:
+    return {k: (float(np.asarray(v)) if np.asarray(v).ndim == 0
+                else np.asarray(v)) for k, v in diag.items()}
+
+
 def _run(cfg, d, an, steps, *, rebalance_every=0, rebalance_skew=0,
-         max_births=512, use_ring=True, seed=3):
+         max_births=512, seed=3):
     """Run the engine; returns (final diag, per-key accumulated sums)."""
     mesh = make_debug_mesh(data=d, model=1)
     ecfg = engine.EngineConfig(
         pic=cfg, axis_names=("data",), async_n=an, max_migration=512,
         max_births=max_births, rebalance_every=rebalance_every,
-        rebalance_skew=rebalance_skew, use_ring=use_ring)
+        rebalance_skew=rebalance_skew)
     state = engine.init_engine_state(ecfg, mesh, seed)
     step = engine.make_engine_step(ecfg, mesh)
     sums: dict = {}
     for _ in range(steps):
         state, diag = step(state)
-        for k, v in diag.items():
-            if k in _SOURCE_KEYS or k.endswith(_SOURCE_SUFFIXES):
-                sums[k] = sums.get(k, 0) + int(np.asarray(v))
-    out = {k: (float(np.asarray(v)) if np.asarray(v).ndim == 0
-               else np.asarray(v)) for k, v in diag.items()}
-    return out, sums
+        _accumulate(sums, diag)
+    return _host_diag(diag), sums
 
 
 def _assert_ionization_conserved(diag, sums, tag):
@@ -140,16 +149,16 @@ def _assert_ionization_conserved(diag, sums, tag):
 # ---------------------------------------------------------------- in-process
 
 
-def test_ionization_conservation_single_domain():
+@pytest.mark.parametrize("an,reb,skew,fs", [
+    (1, 0, 0, False), (2, 3, 0, False), (4, 0, 8, False), (2, 3, 0, True)])
+def test_ionization_conservation_single_domain(an, reb, skew, fs):
     """D=1 across async_n and both rebalance modes (period + skew trigger),
     with and without the field solve: exact pair/charge accounting."""
-    for an, reb, skew, fs in [(1, 0, 0, False), (2, 3, 0, False),
-                              (4, 0, 8, False), (2, 3, 0, True)]:
-        cfg = _ion_cfg(field_solve=fs)
-        diag, sums = _run(cfg, 1, an, 12, rebalance_every=reb,
-                          rebalance_skew=skew)
-        _assert_ionization_conserved(diag, sums, (an, reb, skew, fs))
-        assert sums["birth_overflow"] == 0
+    cfg = _ion_cfg(field_solve=fs)
+    diag, sums = _run(cfg, 1, an, 12, rebalance_every=reb,
+                      rebalance_skew=skew)
+    _assert_ionization_conserved(diag, sums, (an, reb, skew, fs))
+    assert sums["birth_overflow"] == 0
 
 
 def test_birth_budget_overflow_conserves():
@@ -160,31 +169,83 @@ def test_birth_budget_overflow_conserves():
     _assert_ionization_conserved(diag, sums, "budget")
 
 
-def test_ring_vs_legacy_merge_parity_identical_seeds():
-    """use_ring=True vs the legacy full-capacity-scan merge on identical
-    seeds: the SAME events are drawn, so counts/charges match exactly and
-    the energies to float tolerance — only the injection mechanics differ.
+_LANDING_CFGS = {
+    "ionize": lambda: _ion_cfg(),
+    "ionize+field": lambda: _ion_cfg(field_solve=True),
+    "see": _see_cfg,
+    "ionize+see": lambda: _ion_cfg(see=True),
+}
 
-    The parity domain is drop-free traffic (4x capacity headroom here):
-    at the margins the legacy mode keeps the pre-PR-4 loss semantics (a
-    full buffer drops a birth after its neutral died) while the ring path
-    refuses the kill up front — asserted by zero drops below."""
-    for cfg in (_ion_cfg(), _ion_cfg(field_solve=True), _see_cfg(),
-                _ion_cfg(see=True)):
-        ring_d, ring_s = _run(cfg, 1, 2, 10, use_ring=True)
-        leg_d, leg_s = _run(cfg, 1, 2, 10, use_ring=False)
-        for sc in cfg.species:   # inside the drop-free parity domain
-            assert leg_s.get(f"{sc.name}/merge_dropped", 0) == 0, sc.name
-        for k in _SOURCE_KEYS:
-            assert ring_s.get(k, 0) == leg_s.get(k, 0), k
-        for sc in cfg.species:
-            n = sc.name
-            assert ring_d[f"{n}/count"] == leg_d[f"{n}/count"], n
-            assert ring_d[f"{n}/charge"] == leg_d[f"{n}/charge"], n
-            np.testing.assert_allclose(ring_d[f"{n}/ke"], leg_d[f"{n}/ke"],
-                                       rtol=1e-5)
-            assert ring_s.get(f"{n}/emitted", 0) == leg_s.get(
-                f"{n}/emitted", 0), n
+
+def _live_rows(x, v, w, alive) -> np.ndarray:
+    """The live rows (x, v, w) of one species buffer, sorted: a multiset
+    that compares equal whatever slots the rows sit in."""
+    rows = np.column_stack([x, v, w])[alive]
+    return rows[np.lexsort(rows.T[::-1])]
+
+
+@pytest.mark.parametrize("tag", list(_LANDING_CFGS))
+def test_ring_landing_matches_full_scan_injection(tag):
+    """After every step, the pending rows (migration arrivals, ionization
+    pairs, SEE secondaries) land in their ring-claimed slots
+    (``engine._flush_pending``, what the next ingest runs). A full-capacity
+    scan that writes the same rows into the first dead slots of the
+    pre-step buffers (``particles.inject_masked``'s rule, in numpy) must
+    leave the same live rows, per species, as multisets; every accepted
+    ``dest`` must be a distinct slot that was dead before the flush; the
+    step's diag counts must be the flushed buffers'; and the run keeps
+    exact count and charge accounting."""
+    cfg = _LANDING_CFGS[tag]()
+    mesh = make_debug_mesh(data=1, model=1)
+    ecfg = engine.EngineConfig(pic=cfg, axis_names=("data",), async_n=2,
+                               max_migration=512, max_births=512)
+    state = engine.init_engine_state(ecfg, mesh, 3)
+    step = engine.make_engine_step(ecfg, mesh, donate=False)
+    flush = jax.jit(engine._flush_pending)
+    groups = engine._capacity_groups(ecfg, mesh)
+    sums: dict = {}
+    landed = 0
+    for it in range(10):
+        state, diag = step(state)
+        _accumulate(sums, diag)
+        for g, idxs in enumerate(groups):
+            st = stack_species([jax.tree.map(lambda a: a[0],
+                                             state.pic.species[i])
+                                for i in idxs])
+            pend = jax.tree.map(lambda a: a[0], state.pending[g])
+            eff = jax.tree.map(np.asarray, flush(st, pend))
+            st, pend = jax.tree.map(np.asarray, (st, pend))
+            for j, i in enumerate(idxs):
+                ctx = (tag, it, cfg.species[i].name)
+                cap = st.alive.shape[1]
+                ok = pend.alive[j] & (pend.dest[j] < cap)
+                dest = pend.dest[j][ok]
+                assert len(np.unique(dest)) == dest.size, ctx
+                assert not st.alive[j][dest].any(), ctx
+                free = np.flatnonzero(~st.alive[j])[:dest.size]
+                assert free.size == dest.size, ctx
+                x, v, w, alive = (a[j].copy() for a in
+                                  (st.x, st.v, st.w, st.alive))
+                x[free], v[free], w[free] = (pend.x[j][ok], pend.v[j][ok],
+                                             pend.w[j][ok])
+                alive[free] = True
+                np.testing.assert_array_equal(
+                    _live_rows(eff.x[j], eff.v[j], eff.w[j], eff.alive[j]),
+                    _live_rows(x, v, w, alive), err_msg=str(ctx))
+                assert int(np.asarray(
+                    diag[f"{cfg.species[i].name}/count"])) == alive.sum(), ctx
+                landed += dest.size
+    assert landed > 0, (tag, "no pending row landed — test underpowered")
+    diag = _host_diag(diag)
+    if cfg.ionization is not None:
+        _assert_ionization_conserved(diag, sums, tag)
+        return
+    absorbed, emitted = sums["e/wall_absorbed"], sums["e/emitted"]
+    assert absorbed > 0 and emitted > 0, tag
+    assert int(diag["e/count"]) == N0 - absorbed + emitted, tag
+    assert int(diag["D+/count"]) == N0 - sums["D+/wall_absorbed"], tag
+    assert diag["e/charge"] == -float(N0 - absorbed + emitted), tag
+    assert sums["e/merge_dropped"] == 0, tag
 
 
 def test_single_domain_ionize_overflow_keeps_neutrals():

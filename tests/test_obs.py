@@ -1,7 +1,6 @@
 """Observability layer (``repro.obs``): metrics-stream schema, trace
 annotations surviving into the lowered computation, bitwise parity of the
-engine with the metrics toggle on vs off, the monotone-consistent phase
-derivation, probe state-safety, and atomic artifact writes.
+engine with the metrics toggle on vs off, and atomic JSON writes.
 
 The parity matrix (D in {1, 2, 4} x async_n in {1, 2, 4}) needs 4 devices:
 when the process exposes them the check runs in-process; otherwise it
@@ -19,7 +18,7 @@ import jax
 import numpy as np
 
 from repro.core import pic
-from repro.distributed import engine, perf
+from repro.distributed import engine
 from repro.launch.mesh import make_debug_mesh
 from repro.obs import metrics as obs_metrics
 from repro.obs import tracing
@@ -130,10 +129,10 @@ def test_validate_record_rejects_malformed():
 
 
 def test_atomic_write_preserves_existing_on_failure():
-    """An unserializable payload must leave the previous artifact intact
-    (the interrupted-benchmark-truncates-the-trajectory bug)."""
+    """An unserializable payload must leave the previous file intact (an
+    interrupted writer never truncates it)."""
     with tempfile.TemporaryDirectory() as td:
-        path = os.path.join(td, "BENCH_test.json")
+        path = os.path.join(td, "record.json")
         obs_metrics.atomic_write_json(path, {"good": 1})
         try:
             obs_metrics.atomic_write_json(path, {"bad": object()})
@@ -142,7 +141,7 @@ def test_atomic_write_preserves_existing_on_failure():
             pass
         with open(path) as fh:
             assert json.load(fh) == {"good": 1}
-        assert os.listdir(td) == ["BENCH_test.json"]   # no tmp litter
+        assert os.listdir(td) == ["record.json"]       # no tmp litter
 
 
 # ---------------------------------------------------------- trace annotations
@@ -230,81 +229,3 @@ def metrics_parity_matrix():
 
 def test_metrics_toggle_bitwise_parity():
     _dispatch("metrics_parity_matrix")
-
-
-# ----------------------------------------------------------- phase breakdown
-
-
-def _stats(med, lo=None, hi=None):
-    return {"median": float(med), "min": float(lo if lo is not None else med),
-            "max": float(hi if hi is not None else med)}
-
-
-def test_consistent_phases_monotonic_input():
-    """Clean cumulative medians: derived phases ARE the diffs, no flags."""
-    cum = {"ingest": _stats(10), "field": _stats(30), "push": _stats(70),
-           "collide": _stats(90), "migrate": _stats(120),
-           "merge": _stats(150), "full": _stats(160)}
-    phases, flags = perf._consistent_phases(cum)
-    assert flags == []
-    assert phases == {"ingest": 10, "field": 20, "push": 40, "collide": 20,
-                      "migrate": 30, "merge": 30, "diag": 10}
-    assert abs(sum(phases.values()) - 160) < 1e-9
-
-
-def test_consistent_phases_nonmonotonic_is_flagged_not_clamped():
-    """The shipped-artifact failure mode: a cumulative checkpoint larger
-    than the total (and one shorter than its prefix). The derivation must
-    stay internally consistent and the inversions must be flagged."""
-    cum = {"ingest": _stats(10), "field": _stats(30),
-           "push": _stats(20, lo=15, hi=40),        # < field, noise overlap
-           "collide": _stats(90), "migrate": _stats(120),
-           "merge": _stats(500, lo=480, hi=520),    # > total, beyond noise
-           "full": _stats(160, lo=155, hi=170)}
-    phases, flags = perf._consistent_phases(cum)
-    total = cum["full"]["median"]
-    assert all(v >= 0.0 for v in phases.values()), phases
-    assert all(v <= total for v in phases.values()), phases
-    assert abs(sum(phases.values()) - total) < 1e-9
-    # merge is capped at total -> everything after contributes 0, but the
-    # raw 500us measurement is preserved in `cumulative` by the caller
-    assert phases["diag"] == 0.0
-    assert len(flags) == 2, flags
-    assert any("push" in f and "within" in f for f in flags), flags
-    assert any("full" in f and "beyond" in f for f in flags), flags
-
-
-def test_scaling_metrics_carries_probes_and_flags():
-    probe = {"phases": {lbl: 10.0 for lbl in perf.PHASE_LABELS},
-             "total": 70.0,
-             "cumulative": {"full": _stats(70)}, "flags": ["x"]}
-    probe2 = {"phases": {lbl: 5.0 for lbl in perf.PHASE_LABELS},
-              "total": 35.0, "cumulative": {"full": _stats(35)}, "flags": []}
-    out = perf.scaling_metrics({1: probe, 2: probe2})
-    assert out[1]["speedup"] == 1.0
-    assert out[2]["speedup"] == 2.0
-    assert out[2]["parallel_efficiency"] == 1.0
-    assert out[1]["probe_flags"] == ["x"]
-    assert out[1]["cumulative_us"]["full"]["median"] == 70.0
-    assert abs(sum(out[2]["phases"].values()) - out[2]["total"]) < 1e-9
-
-
-# ------------------------------------------------------------- probe safety
-
-
-def test_queue_stats_keeps_caller_state_alive():
-    """The probe donates only a private copy: a caller-provided state must
-    remain readable and unchanged after the probe ran (the old code donated
-    the caller's buffers and fed them back every iteration)."""
-    cfg = _cfg(ionization=False)
-    mesh = make_debug_mesh(data=1, model=1)
-    ecfg = engine.EngineConfig(pic=cfg, axis_names=("data",), async_n=2,
-                               max_migration=64)
-    state = engine.init_engine_state(ecfg, mesh, 0)
-    before = [np.asarray(leaf).copy() for leaf in jax.tree.leaves(state)]
-    stats = perf.queue_stats(ecfg, mesh, steps=2, state=state)
-    assert stats["queue_occ"]
-    after = [np.asarray(leaf) for leaf in jax.tree.leaves(state)]
-    for a, b in zip(before, after):
-        assert np.array_equal(a, b)
-    assert all(len(v) == 2 for v in stats["queue_occ"].values())

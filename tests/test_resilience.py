@@ -11,7 +11,8 @@ The layer this file pins (``ckpt/checkpoint.py`` + ``runtime/resilience.py``
 * **elastic restore** — save at D, restore at D' != D: exact count/charge
   conservation across the boundary, the PR-5-style moment invariants over
   the continued run, and a jaxpr pin that the rebuild does NO full-capacity
-  free-slot scan (``ring_from_counts``, not ``ring_init``);
+  free-slot scan (``ring_from_counts``, not ``ring_init``); a checkpoint
+  with no ``pending/*`` leaves resumes through the same path;
 * **torn writes** — a writer killed between ``arrays.npz`` and
   ``manifest.json`` leaves a checkpoint restart scans straight past;
 * **serialization** — ``_flatten``/unflatten round-trips the engine pytree
@@ -21,6 +22,7 @@ The layer this file pins (``ckpt/checkpoint.py`` + ``runtime/resilience.py``
   fire-once ``FailureInjector``.
 """
 
+import dataclasses
 import os
 import subprocess
 import sys
@@ -333,6 +335,49 @@ def overfull_domain_check():
 
 def test_elastic_restore_rejects_overfull_domain():
     _dispatch("overfull_domain_check")
+
+
+def test_resume_checkpoint_without_pending():
+    """An engine checkpoint with no ``pending/*`` leaves (written by a tree
+    whose state carried no in-flight rows) cannot restore typed, so it
+    resumes through the elastic path, which reads its buffers as they are:
+    counts and charges conserved exactly, pending empty, rings that account
+    for every dead slot, and a state the step runs on."""
+    ecfg = _ecfg(async_n=2)
+    mesh = make_debug_mesh(data=1, model=1)
+    state, _ = resilience.run_engine(
+        ecfg, mesh, engine.init_engine_state(ecfg, mesh, 0), num_steps=3)
+    assert any(np.asarray(p.alive).any() for p in state.pending), \
+        "no rows in flight; the flush below would be vacuous"
+    # land the in-flight rows (a budget retune flushes them), then drop the
+    # pending leaves from what is saved
+    flushed = engine.retarget_state(
+        ecfg, dataclasses.replace(ecfg, max_migration=128), mesh, state)
+    t0 = _totals(ecfg, mesh, flushed)
+    old = engine.EngineState(pic=flushed.pic, rings=flushed.rings,
+                             pending=())
+    with tempfile.TemporaryDirectory() as tmp:
+        ck = Checkpointer(tmp)
+        resilience.save_engine(ck, ecfg, mesh, 3, old, blocking=True)
+        _, flat, _ = ck.restore_flat()
+        assert flat and not any(k.startswith("pending/") for k in flat)
+        assert not resilience._stored_matches(
+            flat, engine.state_shape(ecfg, mesh))       # the elastic path
+        step_r, st = resilience.resume_engine(ecfg, mesh, ck)
+    assert step_r == 3 and int(np.asarray(st.pic.step)) == 3
+    for p in st.pending:
+        assert not np.asarray(p.alive).any()
+    for rg, idxs in zip(st.rings, engine._capacity_groups(ecfg, mesh)):
+        dead = sum(int((~np.asarray(st.pic.species[i].alive)).sum())
+                   for i in idxs)
+        assert int(np.asarray(rg.count).sum()) == dead
+    t1 = _totals(ecfg, mesh, st)
+    for i in t0:
+        assert t1[i][0] == t0[i][0], (i, t0[i], t1[i])
+        np.testing.assert_allclose(t1[i][1], t0[i][1], rtol=1e-12,
+                                   err_msg=f"species {i} charge")
+    st, diags = resilience.run_engine(ecfg, mesh, st, num_steps=5)
+    assert len(diags) == 2 and int(np.asarray(st.pic.step)) == 5
 
 
 def _collect_cumsum_shapes(jxp, out):

@@ -199,8 +199,7 @@ def engine_params_parity_check() -> None:
     for d, async_n in ((1, 2), (2, 2), (4, 4)):
         mesh = make_debug_mesh(data=d, model=1)
         ecfg = pic_bit1.make_engine_config(cfg, async_n=async_n,
-                                           max_migration=512, max_births=256,
-                                           use_ring=True)
+                                           max_migration=512, max_births=256)
         rp = runtime_params(cfg)
         step_a = engine.make_engine_step(ecfg, mesh)
         step_b = engine.make_engine_step(ecfg, mesh, with_params=True)

@@ -397,26 +397,29 @@ def _collect_cumsum_shapes(jxp, out):
     return out
 
 
-def test_merge_does_no_full_capacity_scan():
-    """Regression for the merge-phase bottleneck: the step must contain NO
-    cumsum over a full-capacity axis. The migration exchange legitimately
-    scans each QUEUE (cap / async_n); the old merge's ``free_slots`` scan
-    ran over the whole capacity per species per step and is what the
-    persistent ring eliminated."""
+@pytest.mark.parametrize("async_n", [1, 2, 4])
+def test_merge_does_no_full_capacity_scan(async_n):
+    """Regression for the merge-phase bottleneck: the step's only cumsums
+    over a queue's slots (cap / async_n) are the migration packs', one per
+    queue, and none runs over more. A merge that re-discovers dead slots
+    with ``free_slots`` scans the whole capacity per species per step —
+    what the persistent ring eliminated; at async_n = 1, where a queue is
+    the whole capacity, it would show as a second scan of that length."""
     cap = 8192
     cfg = _engine_cfg(cap=cap, n=4096, nc=64)
     mesh = make_debug_mesh(data=1, model=1)
-    ecfg = engine.EngineConfig(pic=cfg, axis_names=("data",), async_n=2,
-                               max_migration=512)
+    ecfg = engine.EngineConfig(pic=cfg, axis_names=("data",),
+                               async_n=async_n, max_migration=512)
     state = engine.init_engine_state(ecfg, mesh, 0)
     step = engine.make_engine_step(ecfg, mesh, donate=False)
     shapes = _collect_cumsum_shapes(jax.make_jaxpr(step)(state).jaxpr, [])
-    assert shapes, "expected queue-packing cumsums in the exchange"
-    capq = cap // ecfg.async_n
-    assert any(s and s[-1] == capq for s in shapes), shapes
-    full = [s for s in shapes if s and s[-1] >= cap]
-    assert not full, (
-        f"cumsum over a full-capacity axis is back (shapes={full}): the "
+    capq = cap // async_n
+    n_groups = len(engine._capacity_groups(ecfg, mesh))
+    queue_scans = [s for s in shapes if s and s[-1] == capq]
+    assert len(queue_scans) == async_n * n_groups, shapes
+    longer = [s for s in shapes if s and s[-1] > capq]
+    assert not longer, (
+        f"cumsum over more than a queue is back (shapes={longer}): the "
         f"merge phase scales with total capacity again")
 
 
@@ -436,38 +439,40 @@ def _mc_cfg(cap, *, ionization=False, see=False, field_solve=False):
                          field_solve=field_solve, strategy="fused", **kw)
 
 
-def test_mc_source_steps_do_no_full_capacity_scan():
-    """Ionization and SEE engine configs (``_uses_ring`` is gone — the ring
-    path is THE path) must compile with no full-capacity free-slot scan
-    either: ionization packs its events per queue and its births pop
-    pre-claimed ring slots; SEE claims off the already-packed absorbed
-    rows. Only the legacy parity mode (use_ring=False) may scan."""
+_MC_CASES = {
+    "ionization": dict(ionization=True),
+    "ionization+field": dict(ionization=True, field_solve=True),
+    "see": dict(see=True),
+    "ionization+see": dict(ionization=True, see=True),
+}
+
+
+@pytest.mark.parametrize("tag", list(_MC_CASES))
+def test_mc_source_steps_do_no_full_capacity_scan(tag):
+    """Ionization and SEE engine configs must compile with no
+    full-capacity free-slot scan either: ionization packs its events per
+    queue and its births pop pre-claimed ring slots; SEE claims off the
+    already-packed absorbed rows. The assertion bites: the full-scan
+    injection ``particles.inject_masked`` (which the single-domain step
+    still runs, through ``collisions.ionize`` and ``boundaries``) shows a
+    cumsum over the whole capacity."""
     cap = 8192
     mesh = make_debug_mesh(data=1, model=1)
-    cases = {
-        "ionization": _mc_cfg(cap, ionization=True),
-        "ionization+field": _mc_cfg(cap, ionization=True, field_solve=True),
-        "see": _mc_cfg(cap, see=True),
-        "ionization+see": _mc_cfg(cap, ionization=True, see=True),
-    }
-    for tag, cfg in cases.items():
-        ecfg = engine.EngineConfig(pic=cfg, axis_names=("data",), async_n=2,
-                                   max_migration=512, max_births=512)
-        state = engine.init_engine_state(ecfg, mesh, 0)
-        step = engine.make_engine_step(ecfg, mesh, donate=False)
-        shapes = _collect_cumsum_shapes(
-            jax.make_jaxpr(step)(state).jaxpr, [])
-        full = [s for s in shapes if s and s[-1] >= cap]
-        assert not full, (
-            f"[{tag}] full-capacity cumsum is back (shapes={full}): an MC "
-            f"source re-introduced a capacity-scaling scan")
-        # the legacy parity mode still scans — proves the assertion bites
-        legacy = engine.EngineConfig(
-            pic=cfg, axis_names=("data",), async_n=2, max_migration=512,
-            max_births=512, use_ring=False)
-        lstate = engine.init_engine_state(legacy, mesh, 0)
-        lstep = engine.make_engine_step(legacy, mesh, donate=False)
-        lshapes = _collect_cumsum_shapes(
-            jax.make_jaxpr(lstep)(lstate).jaxpr, [])
-        assert any(s and s[-1] >= cap for s in lshapes), (
-            f"[{tag}] expected the legacy full-scan merge to scan")
+    cfg = _mc_cfg(cap, **_MC_CASES[tag])
+    ecfg = engine.EngineConfig(pic=cfg, axis_names=("data",), async_n=2,
+                               max_migration=512, max_births=512)
+    state = engine.init_engine_state(ecfg, mesh, 0)
+    step = engine.make_engine_step(ecfg, mesh, donate=False)
+    shapes = _collect_cumsum_shapes(jax.make_jaxpr(step)(state).jaxpr, [])
+    full = [s for s in shapes if s and s[-1] >= cap]
+    assert not full, (
+        f"[{tag}] full-capacity cumsum is back (shapes={full}): an MC "
+        f"source re-introduced a capacity-scaling scan")
+    m = 512
+    buf = make_species(cap)
+    scan = jax.make_jaxpr(inject_masked)(
+        buf, jnp.zeros((m,)), jnp.zeros((m, 3)), jnp.zeros((m,)),
+        jnp.ones((m,), bool))
+    assert any(s and s[-1] >= cap
+               for s in _collect_cumsum_shapes(scan.jaxpr, [])), (
+        f"[{tag}] expected the full-scan injection to scan the capacity")
