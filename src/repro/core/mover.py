@@ -74,10 +74,12 @@ class PushResult(NamedTuple):
     rho: Array | None = None
 
 
-def boris_kick(v: Array, e_x: Array, qm_dt: Array | float,
+def boris_kick(v: Array, e_x: Array | None, qm_dt: Array | float,
                b: Array | tuple[float, float, float] = (0.0, 0.0, 0.0)
                ) -> Array:
-    """Boris rotation push. v: (N, 3); e_x: (N,) field at particles.
+    """Boris rotation push. v: (N, 3); e_x: (N,) field at particles, or
+    None where E is statically zero: both half kicks are skipped at trace
+    time, and with a zero ``b`` too ``v`` comes back as it went in.
 
     ``b`` may be a static (bx, by, bz) tuple — all-zero skips the rotation
     at trace time — or a (3,) array (traced runtime value); an array always
@@ -85,7 +87,7 @@ def boris_kick(v: Array, e_x: Array, qm_dt: Array | float,
     should pass the tuple to keep the cheaper program.
     """
     half = 0.5 * qm_dt
-    vm = v.at[:, 0].add(half * e_x)              # half electric kick
+    vm = v if e_x is None else v.at[:, 0].add(half * e_x)   # half E kick
     if isinstance(b, jax.Array) or any(c != 0.0 for c in b):
         t = jnp.asarray(b, v.dtype) * half
         t2 = jnp.dot(t, t)
@@ -94,7 +96,7 @@ def boris_kick(v: Array, e_x: Array, qm_dt: Array | float,
         vp = vm + jnp.cross(vprime, s[None, :])
     else:
         vp = vm
-    return vp.at[:, 0].add(half * e_x)           # second half kick
+    return vp if e_x is None else vp.at[:, 0].add(half * e_x)  # 2nd half
 
 
 def apply_boundary(x: Array, alive: Array, length: float,
@@ -132,10 +134,11 @@ def _field_at(x: Array, alive: Array, e: Array, grid: Grid1D,
     return g(grid, e, x) * alive
 
 
-def _move(x: Array, v: Array, alive: Array, e_x: Array, grid: Grid1D,
-          qm_dt: Array | float, dt: Array | float,
+def _move(x: Array, v: Array, alive: Array, e_x: Array | None,
+          grid: Grid1D, qm_dt: Array | float, dt: Array | float,
           b: tuple[float, float, float], boundary: Boundary):
-    """Boris + drift + boundary on raw arrays, given E at the particles."""
+    """Boris + drift + boundary on raw arrays, given E at the particles
+    (None: no electric kick)."""
     v = boris_kick(v, e_x, qm_dt, b)
     x = x + v[:, 0] * dt
     x, alive, hl, hr = apply_boundary(x, alive, grid.length, boundary)
@@ -288,13 +291,23 @@ def push_async_batched(buf: SpeciesBuffer, e: Array, grid: Grid1D, qm: float,
     return PushResult(out, unshape(hl), unshape(hr), diag)
 
 
-def push_stacked(st: StackedSpecies, e: Array, grid: Grid1D, qm: Array,
-                 dt: Array, b: tuple[float, float, float] = (0.0, 0.0, 0.0),
+def push_stacked(st: StackedSpecies, e: Array | None, grid: Grid1D,
+                 qm: Array, dt: Array,
+                 b: tuple[float, float, float] = (0.0, 0.0, 0.0),
                  boundary: Boundary = "periodic", gather_mode: str = "take",
                  charges: Array | None = None,
-                 rho_carry: Array | None = None
+                 rho_carry: Array | None = None,
+                 gather_rows: tuple[int, ...] | None = None
                  ) -> tuple[StackedSpecies, Array, Array, dict, Array | None]:
     """vmap'd Boris push over the species axis of a StackedSpecies.
+
+    ``e`` is the node field, or None for a configuration that solves no
+    field: no E is gathered and no row is kicked (``b`` still rotates).
+    ``gather_rows`` is the static tuple of the rows whose kick can be
+    nonzero (the charged species; None: every row). Only those rows gather
+    E, the others kick with a zero the compiler folds; with no such row
+    nothing is gathered, as with ``e=None``. The rows are picked by static
+    indexing, since a ``take`` would lower to yet another gather.
 
     ``qm`` and ``dt`` are (S,) per-species arrays (q/m and dt*stride). When
     ``charges`` (S,) is given the post-push TOTAL charge density of all
@@ -309,12 +322,27 @@ def push_stacked(st: StackedSpecies, e: Array, grid: Grid1D, qm: Array,
 
     The scopes ``field_gather``, ``move`` and ``deposit`` open outside the
     vmaps: a scope entered inside a vmapped function is named
-    ``vmap(<scope>)`` in the op_name.
+    ``vmap(<scope>)`` in the op_name. ``field_gather`` opens only where
+    some row gathers E.
     """
-    with tracing.phase_scope("field_gather"):
-        e_x = jax.vmap(lambda x, alive: _field_at(x, alive, e, grid,
-                                                  gather_mode))(
-            st.x, st.alive)
+    s_rows = st.x.shape[0]
+    rows = (() if e is None
+            else tuple(range(s_rows)) if gather_rows is None
+            else tuple(gather_rows))
+    e_x = None
+    if rows:
+        with tracing.phase_scope("field_gather"):
+            whole = rows == tuple(range(s_rows))
+            xs, alives = ((st.x, st.alive) if whole else
+                          (jnp.stack([st.x[i] for i in rows]),
+                           jnp.stack([st.alive[i] for i in rows])))
+            e_x = jax.vmap(lambda x, alive: _field_at(x, alive, e, grid,
+                                                      gather_mode))(
+                xs, alives)
+            if not whole:
+                zero = jnp.zeros_like(e_x[0])
+                e_x = jnp.stack([e_x[rows.index(i)] if i in rows else zero
+                                 for i in range(s_rows)])
     with tracing.phase_scope("move"):
         x, v, alive, hl, hr = jax.vmap(
             lambda x, v, alive, e_x, qm_s, dt_s: _move(

@@ -168,6 +168,17 @@ def _carries_rho(cfg: PICConfig) -> bool:
             and all(sc.stride == 1 for sc in cfg.species))
 
 
+def kicked_rows(cfg: PICConfig, idxs) -> tuple[int, ...]:
+    """The rows of the species ``idxs``, stacked in that order, whose
+    electric kick can be nonzero: none when the config solves no field
+    (E is zero), else the charged species' (a neutral has q/m = 0). The
+    stacked mover gathers E for these rows alone."""
+    if not cfg.field_solve:
+        return ()
+    return tuple(j for j, i in enumerate(idxs)
+                 if cfg.species[i].charge != 0.0)
+
+
 def init_state(cfg: PICConfig, seed: int = 0) -> PICState:
     key = jax.random.PRNGKey(seed)
     keys = jax.random.split(key, len(cfg.species) + 1)
@@ -237,7 +248,8 @@ def _push_all(state: PICState, cfg: PICConfig, e: Array,
         out, hl, hr, pdiag, new_rho = mover.push_stacked(
             st, e, grid, qm, dts, b=_b_arg(cfg, rp, dtype),
             boundary=cfg.boundary,
-            gather_mode=cfg.gather_mode, charges=charges)
+            gather_mode=cfg.gather_mode, charges=charges,
+            gather_rows=kicked_rows(cfg, range(len(cfg.species))))
         strides = [sc.stride for sc in cfg.species]
         if any(s > 1 for s in strides):
             # sub-cycling (BIT1's nstep): heavy/neutral species push every

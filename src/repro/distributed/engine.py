@@ -109,7 +109,7 @@ from repro.core.particles import (FreeSlotRing, SpeciesBuffer, StackedSpecies,
                                   ring_from_counts, ring_init, ring_push,
                                   sort_by_cell, stack_species, take)
 from repro.core.params import RuntimeParams, b_active
-from repro.core.pic import PICConfig, PICState
+from repro.core.pic import PICConfig, PICState, kicked_rows
 from repro.core.pic import _carries_rho as pic_carries_rho
 from repro.distributed import halo
 from repro.kernels import ops
@@ -208,6 +208,13 @@ class EngineConfig:
     def queue_births(self) -> int:
         assert self.max_births % self.async_n == 0  # enforced when it matters
         return self.max_births // self.async_n
+
+    @property
+    def field_gather_rows(self) -> int:
+        """Species rows the built step gathers E for in each queue: 0 when
+        the config solves no field, else its charged species (the rows
+        ``pic.kicked_rows`` picks in every capacity group)."""
+        return len(kicked_rows(self.pic, range(len(self.pic.species))))
 
 
 @partial(jax.tree_util.register_dataclass,
@@ -707,9 +714,8 @@ def make_engine_step(ecfg: EngineConfig, mesh: Mesh, *, donate: bool = True,
 
         # ---- field phase: halo exchange, never a full-rho all_gather ----
         with tracing.phase_scope("engine/field"):
-            if not cfg.field_solve:
-                e = jnp.zeros((ncl + 1,), jnp.float32)
-            else:
+            e = None        # no field solve: no E to gather, no kick
+            if cfg.field_solve:
                 if carried and state.rho is not None:
                     rho_local = state.rho[0]
                 else:
@@ -803,7 +809,8 @@ def make_engine_step(ecfg: EngineConfig, mesh: Mesh, *, donate: bool = True,
                            else cfg.b_field),
                         boundary="open", gather_mode=cfg.gather_mode,
                         charges=charges if carried else None,
-                        rho_carry=rho_acc if carried else None)
+                        rho_carry=rho_acc if carried else None,
+                        gather_rows=kicked_rows(cfg, idxs))
                     if any(s > 1 for s in strides):
                         # sub-cycling: heavy species push every `stride`
                         # steps

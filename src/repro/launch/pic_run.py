@@ -245,6 +245,8 @@ def main() -> None:
                                   rebalance_skew=args.rebalance_skew,
                                   cell_order=args.cell_order,
                                   metrics=want_stream)
+        print(f"engine: {args.domains} domain(s), async_n={args.async_n}, "
+              f"field_gather_rows={ecfg.field_gather_rows}")
         state = engine.init_engine_state(ecfg, mesh, 0)
         stream = None
         if want_stream:

@@ -95,6 +95,95 @@ def test_stacked_push_matches_per_species_loop():
                                rtol=1e-4, atol=1e-4)
 
 
+def _stacked_population(qm):
+    g, _, _ = _population()
+    bufs = [init_uniform(jax.random.PRNGKey(s), 2048, 2000, g.length, 3.0)
+            for s in range(len(qm))]
+    return g, stack_species(bufs), jnp.asarray(qm), jnp.asarray(
+        [0.1, 0.2, 0.1][:len(qm)])
+
+
+def _assert_push_outputs(got, want, eq):
+    """``eq(a, b)`` on every output of ``push_stacked``: the stacked
+    species' leaves, both wall masks, each diag value and rho."""
+    (st, hl, hr, diag, rho), (st_w, hl_w, hr_w, diag_w, rho_w) = got, want
+    for name in ("x", "v", "alive", "w"):
+        assert eq(getattr(st, name), getattr(st_w, name)), name
+    assert eq(hl, hl_w) and eq(hr, hr_w)
+    assert diag.keys() == diag_w.keys()
+    for k in diag:
+        assert eq(diag[k], diag_w[k]), k
+    assert (rho is None) == (rho_w is None)
+    if rho is not None:
+        assert eq(rho, rho_w)
+
+
+def _by_value(a, b):
+    return bool((np.asarray(a) == np.asarray(b)).all())
+
+
+@pytest.mark.parametrize("b", [(0.0, 0.0, 0.0), (0.3, 0.0, 0.5)],
+                         ids=["b0", "b"])
+@pytest.mark.parametrize("boundary", ["periodic", "absorb", "open"])
+def test_stacked_push_without_field_equals_zero_field(boundary, b):
+    """``e=None`` (no field solve: no gather, no kick) moves every row
+    exactly as a gathered E of zeros does. Compared by value: the zero
+    kick's ``v + 0.0`` may turn a -0.0 into +0.0, skipping it may not."""
+    g, st, qm, dt = _stacked_population([-1.0, 0.5, 0.0])
+    kw = dict(b=b, boundary=boundary, charges=jnp.asarray([-1.0, 1.0, 0.0]))
+    got = mover.push_stacked(st, None, g, qm, dt, **kw)
+    want = mover.push_stacked(st, jnp.zeros((g.ng,)), g, qm, dt, **kw)
+    _assert_push_outputs(got, want, _by_value)
+    if boundary == "absorb":
+        assert int(jnp.sum(got[1] | got[2])) > 0
+
+
+@pytest.mark.parametrize("qm", [[-1.0, 0.5, 0.0], [-1.0, 0.0, 0.5]],
+                         ids=["neutral_last", "neutral_between"])
+@pytest.mark.parametrize("gather_mode", ["take", "onehot"])
+def test_stacked_push_gathers_charged_rows_only(gather_mode, qm):
+    """With a nonzero E, gathering only the charged rows moves them
+    bitwise as the gather over every row does, and the neutral row (q/m =
+    0, kicked by a folded zero) by value."""
+    g, st, qm_a, dt = _stacked_population(qm)
+    e = 3.0 * jax.random.normal(jax.random.PRNGKey(5), (g.ng,))
+    charged = tuple(j for j, q in enumerate(qm) if q != 0.0)
+    kw = dict(boundary="absorb", gather_mode=gather_mode,
+              charges=jnp.asarray(qm))
+    got = mover.push_stacked(st, e, g, qm_a, dt, gather_rows=charged, **kw)
+    want = mover.push_stacked(st, e, g, qm_a, dt, **kw)
+    for j in range(len(qm)):
+        eq = ((lambda a, b: np.asarray(a).tobytes()
+               == np.asarray(b).tobytes()) if j in charged else _by_value)
+        row = lambda out: (jax.tree.map(lambda a: a[j], out[0]), out[1][j],
+                           out[2][j], {k: v[j] for k, v in out[3].items()},
+                           None)
+        _assert_push_outputs(row(got), row(want), eq)
+    assert _by_value(got[4], want[4])           # the deposit of all rows
+    assert not _by_value(got[0].v[charged[0]], st.v[charged[0]])  # kicked
+
+
+@pytest.mark.parametrize("cfg, rows", [
+    ("bench", 0), ("resilience", 2), ("two_neutrals", 1)])
+def test_engine_field_gather_rows(cfg, rows):
+    """The built step gathers E for no row with the field off, and with
+    it on for the charged species alone."""
+    from repro.configs.pic_bit1 import (make_bench_config,
+                                        make_engine_config,
+                                        make_resilience_config)
+
+    if cfg == "bench":
+        pcfg = make_bench_config(nc=64, n=1024)
+    else:
+        pcfg = make_resilience_config(nc=64, n=1024)
+    if cfg == "two_neutrals":
+        pcfg = pic.PICConfig(nc=64, dx=1.0, dt=0.2, species=(
+            pic.SpeciesConfig("D", 0.0, 3672.0, 2048, 1024, vth=0.05),
+            pic.SpeciesConfig("e", -1.0, 1.0, 2048, 1024, vth=1.0),
+            pic.SpeciesConfig("H", 0.0, 1836.0, 2048, 1024, vth=0.05)))
+    assert make_engine_config(pcfg).field_gather_rows == rows
+
+
 def _wall_cfg(cap_primary=4096, cap_target=4096, strategy="unified"):
     sp = (
         pic.SpeciesConfig("e", -1.0, 1.0, cap_primary, cap_primary // 2,

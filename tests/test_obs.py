@@ -178,6 +178,24 @@ def test_engine_phase_scopes_reach_the_jaxpr():
         assert any(want in s for s in stacks), (want, len(stacks))
 
 
+def test_field_off_engine_step_opens_no_field_gather():
+    """With the field solve off the push has no E to gather: the engine
+    step's jaxpr holds no ``field_gather`` scope, its move still runs
+    under ``engine/push/q<k>/move``, and the step reports 0 gathered
+    rows."""
+    cfg = _cfg()
+    assert not cfg.field_solve
+    mesh = make_debug_mesh(data=1, model=1)
+    ecfg = engine.EngineConfig(pic=cfg, axis_names=("data",), async_n=2,
+                               max_migration=64, max_births=64)
+    assert ecfg.field_gather_rows == 0
+    state = engine.init_engine_state(ecfg, mesh, 0)
+    step = engine.make_engine_step(ecfg, mesh, donate=False)
+    stacks = tracing.jaxpr_scope_names(jax.make_jaxpr(step)(state))
+    assert any("engine/push/q1/move" in s for s in stacks)
+    assert not [s for s in stacks if "field_gather" in s]
+
+
 def test_trace_session_writes_capture():
     """start/stop capture around real device work produces trace files;
     a None profile dir is a no-op."""

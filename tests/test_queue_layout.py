@@ -12,6 +12,10 @@ dead rows. A whole engine step run through the oracles gives the same
 state and diag, bit for bit.
 """
 
+import os
+import subprocess
+import sys
+
 import numpy as np
 import pytest
 
@@ -23,6 +27,9 @@ from repro.core.collisions import CollisionConfig
 from repro.core.particles import StackedSpecies
 from repro.distributed import engine
 from repro.launch.mesh import make_debug_mesh
+
+SRC = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..", "src")
+HERE = os.path.dirname(os.path.abspath(__file__))
 
 # f32 bit patterns put in the dead rows: -0.0, +inf, -inf, a quiet NaN
 # with a payload, a negative NaN, the smallest and the largest subnormal
@@ -185,24 +192,56 @@ def _run_steps(traffic, n, steps=3, seed=11):
     return jax.device_get(state), diags
 
 
+def _dump_both_forms(traffic, n, path):
+    """Three steps of one seed through the engine as it is, then through
+    the engine with the index forms put back; every state leaf and diag
+    value of both into ``path`` (npz). Run by the test below in a child
+    process."""
+    out = {}
+    for tag in ("new", "old"):
+        if tag == "old":
+            engine._split_queues = old_split_queues
+            engine._merge_queues = old_merge_queues
+            engine._queue_occupancy = old_occupancy
+        state, diags = _run_steps(traffic, n)
+        for i, leaf in enumerate(jax.tree.leaves(state)):
+            out[f"{tag}/leaf{i}"] = np.asarray(leaf)
+        for s, d in enumerate(diags):
+            for k, v in d.items():
+                out[f"{tag}/diag{s}/{k}"] = np.asarray(v)
+    np.savez(path, **out)
+
+
 @pytest.mark.parametrize("n", [2, 4])
 @pytest.mark.parametrize("traffic", sorted(TRAFFIC))
-def test_engine_step_equals_index_forms(monkeypatch, traffic, n):
+def test_engine_step_equals_index_forms(tmp_path, traffic, n):
     """Three steps of one seed through the engine as it is and through the
     engine with the index forms put back: the same state and diag, bit
-    for bit."""
-    state, diags = _run_steps(traffic, n)
-    monkeypatch.setattr(engine, "_split_queues", old_split_queues)
-    monkeypatch.setattr(engine, "_merge_queues", old_merge_queues)
-    monkeypatch.setattr(engine, "_queue_occupancy", old_occupancy)
-    old_state, old_diags = _run_steps(traffic, n)
-    leaves, old_leaves = (jax.tree.leaves(s) for s in (state, old_state))
-    assert len(leaves) == len(old_leaves)
-    for a, b in zip(leaves, old_leaves):
-        _same(a, b)
-    for d, od in zip(diags, old_diags):
-        assert d.keys() == od.keys()
-        for k in d:
-            _same(d[k], od[k])
-    assert any(int(np.asarray(d["n_ionized" if traffic == "ionize"
-                                 else "coll_coulomb"])) > 0 for d in diags)
+    for bit.
+
+    Both run in a child process whose XLA:CPU emits no fused multiply-add
+    (``--xla_cpu_max_isa=SSE4_2``). The push hands an unkicked v on as the
+    split made it, so XLA fuses the split, or the oracle's gather, into
+    what reads v next; with FMA, LLVM contracts the elastic collision's
+    sum of squares one way or the other by that producer, and the speed
+    comes out one ulp apart though the layouts moved the same bits.
+    Without FMA every op rounds once, wherever it is fused."""
+    path = tmp_path / "steps.npz"
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=os.pathsep.join(
+        [SRC, HERE, os.environ.get("PYTHONPATH", "")]))
+    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
+                        + " --xla_cpu_max_isa=SSE4_2").strip()
+    prog = (f"from test_queue_layout import _dump_both_forms; "
+            f"_dump_both_forms({traffic!r}, {n}, {str(path)!r})")
+    run = subprocess.run([sys.executable, "-c", prog], env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert run.returncode == 0, run.stderr[-3000:]
+    got = np.load(path)
+    new = {k[4:]: got[k] for k in got.files if k.startswith("new/")}
+    old = {k[4:]: got[k] for k in got.files if k.startswith("old/")}
+    assert new and new.keys() == old.keys()
+    for k in new:
+        _same(new[k], old[k])
+    counter = "n_ionized" if traffic == "ionize" else "coll_coulomb"
+    assert any(int(v) > 0 for k, v in new.items()
+               if k.endswith(f"/{counter}"))

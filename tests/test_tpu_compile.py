@@ -16,7 +16,9 @@ The engine step is compiled too, at a small size, on one domain and on the
 four of the 2x2, to pin its phase-scope coverage on the program the chip
 runs: every instruction whose op_name the program wrote lies under an
 ``engine/`` or ``halo/`` scope, and on four domains every collective lies
-under one of the exchange scopes.
+under one of the exchange scopes. With the field solve off the mover
+gathers no E; a field-on step, on one domain, gathers it for the charged
+species alone.
 """
 
 import math
@@ -211,30 +213,48 @@ def _top_level_op_names(hlo: str) -> dict:
 ENGINE_CAP, ENGINE_ASYNC_N = 2 ** 14, 2
 
 
-@pytest.fixture(scope="module", params=[1, 4], ids=["d1", "d4"])
-def engine_hlo(request, topo):
-    """The ionization step on one v5e domain and on the four of the 2x2
-    (nc 4,096 and 2**14 slots per species per domain, async_n 2), compiled
-    as the chip runs it: the Pallas kernels compiled, not interpreted
-    (``ops`` sees the CPU backend here). Returns (domains, HLO text)."""
+ENGINE_NC = 4096               # cells per domain
+
+
+def _engine_hlo(topo, d: int, field_solve: bool) -> str:
+    """The ionization step on ``d`` v5e domains (ENGINE_NC cells and
+    ENGINE_CAP slots per species per domain, ENGINE_ASYNC_N queues),
+    compiled as the chip runs it: the Pallas kernels compiled, not
+    interpreted (``ops`` sees the CPU backend here). Returns HLO text."""
+    import dataclasses
+
     import numpy as np
     from jax.sharding import Mesh
 
     from repro.configs.pic_bit1 import make_bench_config, make_engine_config
     from repro.distributed import engine
 
-    d = request.param
     mesh = Mesh(np.asarray(topo.devices[:d]).reshape(d, 1), ("data", "model"))
+    cfg = make_bench_config(nc=ENGINE_NC * d, n=d * ENGINE_CAP // 2)
     ecfg = make_engine_config(
-        make_bench_config(nc=4096 * d, n=d * ENGINE_CAP // 2),
+        dataclasses.replace(cfg, field_solve=field_solve),
         async_n=ENGINE_ASYNC_N, max_migration=512, max_births=1024)
     args = jax.tree.map(
         lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
         engine.state_shape(ecfg, mesh), engine.state_shardings(ecfg, mesh))
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(ops, "_interpret", lambda: False)
-        return d, engine.make_engine_step(ecfg, mesh).lower(args).compile(
+        return engine.make_engine_step(ecfg, mesh).lower(args).compile(
             ).as_text()
+
+
+@pytest.fixture(scope="module", params=[1, 4], ids=["d1", "d4"])
+def engine_hlo(request, topo):
+    """The bench config's step (field solve off, as in the paper's section
+    3.3 test) on one domain and on the four of the 2x2. Returns (domains,
+    HLO text)."""
+    return request.param, _engine_hlo(topo, request.param, False)
+
+
+@pytest.fixture(scope="module")
+def field_on_hlo(topo):
+    """The same step on one domain with the field solve on."""
+    return _engine_hlo(topo, 1, True)
 
 
 def test_engine_step_ops_are_all_scoped(engine_hlo):
@@ -251,9 +271,9 @@ def test_engine_step_ops_are_all_scoped(engine_hlo):
                if op.startswith("jit(") and not _SCOPED.search(op)
                and not (d > 1 and _PARTITIONER.match(op))}
     assert not outside, outside
-    for scope in ("engine/split/", "engine/push/q0/field_gather/",
-                  "engine/push/q1/move/", "engine/merge/layout/",
-                  "engine/migrate/q0/pack/", "engine/ionize/q1/draw/"):
+    for scope in ("engine/split/", "engine/push/q1/move/",
+                  "engine/merge/layout/", "engine/migrate/q0/pack/",
+                  "engine/ionize/q1/draw/"):
         assert any(scope in op for op in ops_.values()), scope
     if d == 1:
         return
@@ -277,6 +297,52 @@ def test_engine_step_ops_are_all_scoped(engine_hlo):
 
 _DEF = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*([a-z]\w*)"
                   r"\[([\d,]*)\]")
+_GATHER = re.compile(r"=\s*\S+\s+gather\(%?([\w.\-]+),")
+_FIELD_GATHER = re.compile(r"engine/push/q\d+/field_gather(?:/|$)")
+
+
+def _node_gathers(hlo: str) -> list:
+    """``(op_name, result dims)`` of every gather, fused or not, whose
+    operand is an f32 array over the ENGINE_NC + 1 nodes of a domain."""
+    lines = hlo.splitlines()
+    defs = {m.group(1): m.groups()[1:] for m in map(_DEF.match, lines) if m}
+    out = []
+    for line in lines:
+        m = _GATHER.search(line)
+        if m and defs.get(m.group(1)) == ("f32", str(ENGINE_NC + 1)):
+            op = _OP_NAME.search(line)
+            out.append((op.group(1) if op else "",
+                        _DEF.match(line).group(3)))
+    return out
+
+
+def test_field_off_step_gathers_no_field(engine_hlo):
+    """With the field solve off E is zero, so the mover kicks no row: no
+    op of the step lies under ``engine/push/q<k>/field_gather``, and the
+    only gathers from a node array are the MC draw's n_e at the neutrals.
+    A push that gathered a zero E over every queue row failed here."""
+    _, hlo = engine_hlo
+    scoped = [op for op in _OP_NAME.findall(hlo) if _FIELD_GATHER.search(op)]
+    assert not scoped, scoped[:4]
+    gathers = _node_gathers(hlo)
+    assert any("engine/ionize/q0/draw/" in op for op, _ in gathers)
+    loose = [g for g in gathers if "/draw/" not in g[0]]
+    assert not loose, loose
+
+
+def test_field_on_step_gathers_charged_rows_only(field_on_hlo):
+    """With the field solve on, each queue's push gathers E under
+    ``field_gather`` for the charged species alone: a gather of 2 stacked
+    rows (e-, D+) of the queue's slots, none of the 3 with the neutral D."""
+    gathers = [(op, dims) for op, dims in _node_gathers(field_on_hlo)
+               if _FIELD_GATHER.search(op)]
+    rows = ENGINE_CAP // ENGINE_ASYNC_N
+    for k in range(ENGINE_ASYNC_N):
+        assert any(f"engine/push/q{k}/field_gather/" in op
+                   for op, _ in gathers), (k, gathers)
+    assert all(dims == f"2,{rows}" for _, dims in gathers), gathers
+
+
 _INDEXED = re.compile(r"=\s*\S+\s+(gather|scatter)\(%?[\w.\-]+,\s*%?"
                       r"([\w.\-]+)")
 
